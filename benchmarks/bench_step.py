@@ -51,6 +51,8 @@ Run:  PYTHONPATH=src python benchmarks/bench_step.py [--quick]
 
 import os
 
+# CPU emulation tool: pinned to the CPU so it never claims an accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=8")
 

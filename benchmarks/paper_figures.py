@@ -377,6 +377,7 @@ def fig10_wrapper_overhead():
     vs a raw lax.psum on 8 virtual devices (subprocess: the device
     count is process-global and benches must see 1 device)."""
     import json
+    import os
     import subprocess
     import sys
 
@@ -404,8 +405,9 @@ print(json.dumps({"flat": t(flat), "hier": t(hier)}))
 """
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
                           text=True, timeout=300,
-                          env={"PYTHONPATH": "src", "HOME": "/root",
-                               "PATH": "/usr/bin:/bin"})
+                          env={"PYTHONPATH": "src", "JAX_PLATFORMS": "cpu",
+                               "HOME": os.environ.get("HOME", ""),
+                               "PATH": os.environ.get("PATH", "/usr/bin:/bin")})
     line = proc.stdout.strip().splitlines()[-1]
     d = json.loads(line)
     ovh = (d["hier"] - d["flat"]) / d["flat"] * 100

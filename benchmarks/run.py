@@ -7,9 +7,14 @@ verification via HLO, kernel wall-times in interpret mode).
 
 from __future__ import annotations
 
+import os
 import pathlib
 import sys
 import time
+
+# CPU emulation harness (interpret-mode kernels, cost model): pinned to
+# the CPU for itself and the children it starts
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # make `python benchmarks/run.py` work from anywhere: the benchmarks
 # package lives next to this file's parent

@@ -10,6 +10,7 @@ real pod sizes.
 
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"  # virtual CPU devices, never the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import jax

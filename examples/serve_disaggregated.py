@@ -10,6 +10,7 @@ transferred cache must match same-pod generation token-for-token.
 
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"  # virtual CPU devices, never the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import functools
@@ -20,13 +21,13 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
-from repro.launch.mesh import make_test_mesh, runtime_for_mesh
+from repro.launch.mesh import make_local_mesh, runtime_for_mesh
 from repro.models import Model
 from repro.serve import make_kv_transfer, make_serve_steps
 from repro.parallel.sharding import shard_map
 from repro.serve.serve_step import kv_transfer_body
 
-mesh = make_test_mesh()  # (pod=2, data=2, model=2)
+mesh = make_local_mesh()  # (pod=2, data=2, model=2)
 rt = runtime_for_mesh(mesh, moe_capacity_factor=8.0)
 cfg = get_config("qwen2.5-3b", smoke=True)
 model = Model(cfg, rt)

@@ -485,6 +485,8 @@ def _bucket(tree: Any) -> tuple[dict[Any, jax.Array], Any, list]:
 
 
 def _unbucket(joined: dict, treedef, meta) -> Any:
+    # barrier: see packing.unpack (the same compile-time blowup)
+    joined = lax.optimization_barrier(joined)
     offs = {dt: 0 for dt in joined}
     leaves = []
     for dt, shape, size in meta:

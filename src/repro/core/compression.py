@@ -65,7 +65,7 @@ def use_pallas() -> bool:
 def _block_amax(xf: jax.Array) -> jax.Array:
     """Per-block |max| of flat f32 ``xf`` -> (nb,) f32 (one read pass)."""
     if use_pallas():
-        return _qk.amax_block_call(xf, interpret=jax.default_backend() != "tpu")
+        return _qk.amax_block_call(xf)
     return jnp.max(jnp.abs(xf.reshape(-1, BLOCK)), axis=1)
 
 
@@ -76,8 +76,7 @@ def _encode_scaled(xf: jax.Array, scale: jax.Array) -> jax.Array:
     clamp) divides as 1.0 — the block is all zeros anyway, so the guard
     only keeps NaN/inf off the wire."""
     if use_pallas():
-        return _qk.quant_scaled_call(xf, scale,
-                                     interpret=jax.default_backend() != "tpu")
+        return _qk.quant_scaled_call(xf, scale)
     blocks = xf.reshape(-1, BLOCK)
     safe = jnp.where(scale > 0, scale, 1.0)
     return jnp.clip(jnp.round(blocks / safe[:, None]),
@@ -92,8 +91,7 @@ def _decode(q: jax.Array, scale: jax.Array, gain=None) -> jax.Array:
     kernel reads either width (it upcasts to f32 in-register), so the
     hot collective decode stays fused too."""
     if use_pallas() and q.dtype in (jnp.int8, jnp.int32):
-        return _qk.dequant_int8_call(q, scale, gain=gain,
-                                     interpret=jax.default_backend() != "tpu")
+        return _qk.dequant_int8_call(q, scale, gain=gain)
     if gain is not None:
         scale = scale * gain
     return (q.astype(jnp.float32) * scale[:, None]).reshape(-1)
@@ -194,7 +192,7 @@ def quantize_int8(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     serving KV-cache transfer and the kernel reference path)."""
     xf, _ = _flat_blocks(x)
     if use_pallas():
-        return _qk.quant_int8_call(xf, interpret=jax.default_backend() != "tpu")
+        return _qk.quant_int8_call(xf)
     amax = _block_amax(xf)
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
     return _encode_scaled(xf, scale), scale

@@ -27,6 +27,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_interpret
+
 NEG_INF = -1e30
 
 
@@ -87,7 +89,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
                          block_q: int = 128, block_k: int = 128,
                          sm_scale: float | None = None,
                          valid_kv: int | None = None,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool | None = None) -> jax.Array:
     """q: (B, H, Sq, dh), k/v: (B, K, Skv, dh) -> (B, H, Sq, dh).
 
     Sq/Skv padded to block multiples by the caller (ops.py).  dh should
@@ -127,5 +129,5 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(q, k, v)

@@ -1,7 +1,8 @@
 """jit'd dispatch wrappers over the Pallas kernels.
 
 Each op takes the model-layer layout, handles padding/transposes, calls
-the kernel (interpret=True on CPU, compiled on TPU), and exposes the
+the kernel (interpreted on the CPU, compiled on the TPU —
+``repro.kernels.pallas_interpret``), and exposes the
 exact same semantics as the pure-jnp oracle in ref.py (tests sweep
 shapes/dtypes and assert_allclose the two).
 """
@@ -31,7 +32,7 @@ def _pad_axis(x, axis, mult):
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     q_offset=0, block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q: (B, Sq, H, dh); k/v: (B, Skv, K, dh) -> (B, Sq, H, dh).
 
     Model layout is sequence-major; the kernel wants head-major — the
@@ -65,7 +66,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
 
 
 def ssd_chunked(x, dt, A, B, C, chunk: int = 128, h0=None,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """Same contract as ref.ssd_chunked: x (b,s,h,p), dt (b,s,h), A (h,),
     B/C (b,s,g,n) -> (y (b,s,h,p), final state (b,h,p,n))."""
     b, s, h, p = x.shape
@@ -108,13 +109,13 @@ def ssd_chunked(x, dt, A, B, C, chunk: int = 128, h0=None,
     return y.astype(x.dtype), h_last
 
 
-def causal_conv1d(x, w, bias=None, *, interpret: bool = True):
+def causal_conv1d(x, w, bias=None, *, interpret: bool | None = None):
     """Depthwise causal conv; small filter — the jnp form already fuses
     into a few VPU ops, no dedicated kernel needed."""
     return ref.causal_conv1d(x, w, bias)
 
 
-def quant_int8(x: jax.Array, *, interpret: bool = True):
+def quant_int8(x: jax.Array, *, interpret: bool | None = None):
     """x: any shape -> (q (nb, 1024) int8, scales (nb,), orig_size)."""
     flat = x.astype(jnp.float32).reshape(-1)
     pad = (-flat.size) % _q.BLOCK
@@ -125,6 +126,6 @@ def quant_int8(x: jax.Array, *, interpret: bool = True):
 
 
 def dequant_int8(q, s, size: int, shape, dtype=jnp.float32, *,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
     flat = _q.dequant_int8_call(q, s, dtype=dtype, interpret=interpret)
     return flat[:size].reshape(shape)

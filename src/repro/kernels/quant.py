@@ -37,57 +37,73 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import pallas_interpret
+
 BLOCK = 1024
+# blocks per grid step: a multiple of int8's 32-row native tile, so every
+# operand tiles natively on the TPU (the f32/int32 payload, the int8 wire
+# and the (rows, 1) per-block scale column).  256 rows keep the largest
+# double-buffered working set (int32 in + f32 out) at 4 MiB of VMEM.
+ROWS = 256
+
+
+def _row_spec(nb: int, width: int) -> pl.BlockSpec:
+    """(rows, width) tiles over an (nb, width) array: ROWS rows per step,
+    or the whole array when it is smaller (a block equal to the full
+    dims is always legal).  A ragged last step reads padding rows whose
+    writes are dropped, and every row is independent."""
+    return pl.BlockSpec((min(nb, ROWS), width), lambda i: (i, 0))
+
+
+def _grid(nb: int) -> tuple[int]:
+    return (pl.cdiv(nb, ROWS),)
 
 
 def _quant_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[0].astype(jnp.float32)                 # (BLOCK,)
-    amax = jnp.max(jnp.abs(x))
+    x = x_ref[...].astype(jnp.float32)                       # (rows, BLOCK)
+    amax = jnp.max(jnp.abs(x), axis=1, keepdims=True)        # (rows, 1)
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(x / scale), -127, 127)
-    q_ref[0] = q.astype(jnp.int8)
-    s_ref[0, 0] = scale
+    q_ref[...] = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
+    s_ref[...] = scale
 
 
 def _dequant_kernel(q_ref, s_ref, x_ref):
-    x_ref[0] = (q_ref[0].astype(jnp.float32) * s_ref[0, 0]).astype(x_ref.dtype)
+    x_ref[...] = (q_ref[...].astype(jnp.float32) * s_ref[...]).astype(x_ref.dtype)
 
 
 def _amax_kernel(x_ref, a_ref):
-    a_ref[0, 0] = jnp.max(jnp.abs(x_ref[0].astype(jnp.float32)))
+    a_ref[...] = jnp.max(jnp.abs(x_ref[...].astype(jnp.float32)), axis=1,
+                         keepdims=True)
 
 
 def _quant_scaled_kernel(x_ref, s_ref, q_ref):
-    x = x_ref[0].astype(jnp.float32)
+    x = x_ref[...].astype(jnp.float32)
     # an all-zero block can reach this kernel with scale 0 from callers
     # that skip the shared-scale clamp; dividing by it would put
     # NaN/inf on the wire, so guard exactly like _quant_kernel does
     # (the block is all zeros, so any positive scale encodes it as 0)
-    s = s_ref[0, 0]
+    s = s_ref[...]
     scale = jnp.where(s > 0, s, 1.0)
-    q = jnp.clip(jnp.round(x / scale), -127, 127)
-    q_ref[0] = q.astype(jnp.int8)
+    q_ref[...] = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
 
 
-def quant_int8_call(x: jax.Array, *, interpret: bool = True):
+def quant_int8_call(x: jax.Array, *, interpret: bool | None = None):
     """x: flat (N,) with N % BLOCK == 0 -> (q (nb, BLOCK) int8, s (nb,) f32)."""
     assert x.ndim == 1 and x.size % BLOCK == 0, x.shape
     nb = x.size // BLOCK
-    xb = x.reshape(nb, BLOCK)
     q, s = pl.pallas_call(
         _quant_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (i, 0))],
+        grid=_grid(nb),
+        in_specs=[_row_spec(nb, BLOCK)],
+        out_specs=[_row_spec(nb, BLOCK), _row_spec(nb, 1)],
         out_shape=[jax.ShapeDtypeStruct((nb, BLOCK), jnp.int8),
                    jax.ShapeDtypeStruct((nb, 1), jnp.float32)],
-        interpret=interpret,
-    )(xb)
+        interpret=pallas_interpret(interpret),
+    )(x.reshape(nb, BLOCK))
     return q, s[:, 0]
 
 
-def amax_block_call(x: jax.Array, *, interpret: bool = True) -> jax.Array:
+def amax_block_call(x: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """x: flat (N,) with N % BLOCK == 0 -> per-block |max| (nb,) f32.
     The one read pass of the shared-scale collective codec (the caller
     pmax'es the result across the comm axis before quantizing)."""
@@ -95,33 +111,31 @@ def amax_block_call(x: jax.Array, *, interpret: bool = True) -> jax.Array:
     nb = x.size // BLOCK
     a = pl.pallas_call(
         _amax_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
+        grid=_grid(nb),
+        in_specs=[_row_spec(nb, BLOCK)],
+        out_specs=_row_spec(nb, 1),
         out_shape=jax.ShapeDtypeStruct((nb, 1), jnp.float32),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(x.reshape(nb, BLOCK))
     return a[:, 0]
 
 
 def quant_scaled_call(x: jax.Array, scale: jax.Array, *,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     """Quantize flat ``x`` with a caller-provided per-block scale
     (shared-scale codec): one fused scale+round+clip+cast pass.
     Cluster-weight folding happens in the nb-sized ``scale`` argument
     (pass ``scale / w``), never on the payload."""
     assert x.ndim == 1 and x.size % BLOCK == 0, x.shape
     nb = x.size // BLOCK
-    q = pl.pallas_call(
+    return pl.pallas_call(
         _quant_scaled_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
+        grid=_grid(nb),
+        in_specs=[_row_spec(nb, BLOCK), _row_spec(nb, 1)],
+        out_specs=_row_spec(nb, BLOCK),
         out_shape=jax.ShapeDtypeStruct((nb, BLOCK), jnp.int8),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(x.reshape(nb, BLOCK), scale.reshape(nb, 1))
-    return q
 
 
 def _pack_leaf_kernel(off, n, buf_ref, leaf_ref, o_ref):
@@ -135,7 +149,8 @@ def _pack_leaf_kernel(off, n, buf_ref, leaf_ref, o_ref):
 
 
 def pack_slots_call(pieces, padded: int, dtype=jnp.float32, *,
-                    buf: jax.Array | None = None, interpret: bool = True):
+                    buf: jax.Array | None = None,
+                    interpret: bool | None = None):
     """Scatter-pack ``pieces = [(offset, leaf), ...]`` (offsets static,
     from the ``PackedLayout`` slot map) into one padded 1-D buffer with
     Pallas in-place writes.  ``buf`` is the persistent comm buffer to
@@ -150,12 +165,13 @@ def pack_slots_call(pieces, padded: int, dtype=jnp.float32, *,
             functools.partial(_pack_leaf_kernel, int(off), flat.size),
             out_shape=jax.ShapeDtypeStruct((padded,), dtype),
             input_output_aliases={0: 0},
-            interpret=interpret,
+            interpret=pallas_interpret(interpret),
         )(buf, flat)
     return buf
 
 
-def fused_pack_quant_call(pieces, padded: int, *, interpret: bool = True):
+def fused_pack_quant_call(pieces, padded: int, *,
+                          interpret: bool | None = None):
     """Fused pack+quantize for a BLOCK-aligned segment: leaf slices are
     scattered straight into the comm buffer via the slot map (aliased
     in-place writes, no concatenate), then ONE amax+scale+round+clip
@@ -171,21 +187,20 @@ def fused_pack_quant_call(pieces, padded: int, *, interpret: bool = True):
 
 def dequant_int8_call(q: jax.Array, s: jax.Array, *, dtype=jnp.float32,
                       gain: jax.Array | float | None = None,
-                      interpret: bool = True) -> jax.Array:
-    """Decode (nb, BLOCK) int8 with per-block scale ``s``.  ``gain``
-    is the fused epilogue: any post-sum scalar (cluster weight, 1/n
-    mean) multiplies the nb-sized scale vector here instead of costing
-    a payload-sized HBM pass after the decode."""
+                      interpret: bool | None = None) -> jax.Array:
+    """Decode (nb, BLOCK) int8 (or the ring's int32 sums) with per-block
+    scale ``s``.  ``gain`` is the fused epilogue: any post-sum scalar
+    (cluster weight, 1/n mean) multiplies the nb-sized scale vector here
+    instead of costing a payload-sized HBM pass after the decode."""
     nb = q.shape[0]
     if gain is not None:
         s = s * gain
     out = pl.pallas_call(
         _dequant_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
+        grid=_grid(nb),
+        in_specs=[_row_spec(nb, BLOCK), _row_spec(nb, 1)],
+        out_specs=_row_spec(nb, BLOCK),
         out_shape=jax.ShapeDtypeStruct((nb, BLOCK), dtype),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(q, s.reshape(nb, 1).astype(jnp.float32))
     return out.reshape(-1)

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from repro.kernels import pallas_interpret
+
 NEG = -1e30
 
 
@@ -56,7 +58,7 @@ def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
     st_ref[0, 0, 0] = st
 
 
-def ssd_chunk_call(xc, dtc, A, Bc, Cc, *, interpret: bool = True):
+def ssd_chunk_call(xc, dtc, A, Bc, Cc, *, interpret: bool | None = None):
     """xc: (b, nc, h, q, p); dtc: (b, nc, h, q); A: (h,);
     Bc/Cc: (b, nc, h, q, n)  ->  (y_diag (b,nc,h,q,p) f32,
     states (b,nc,h,p,n) f32)."""
@@ -81,5 +83,5 @@ def ssd_chunk_call(xc, dtc, A, Bc, Cc, *, interpret: bool = True):
             jax.ShapeDtypeStruct((b, nc, h, q, p), jnp.float32),
             jax.ShapeDtypeStruct((b, nc, h, p, n), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(xc, dtc, A, Bc, Cc)

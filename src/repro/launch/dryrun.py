@@ -1,10 +1,12 @@
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 MUST set the virtual device count before ANY other import — jax locks
-the device count on first init.
+the device count on first init.  Pinned to the CPU: the 512 devices are
+virtual host devices, and the run must never claim an accelerator.
 """
 
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
 

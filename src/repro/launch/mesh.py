@@ -1,12 +1,14 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-``make_production_mesh`` is a function (never a module-level constant)
-so importing this module never touches jax device state.  The dry-run
-entry point (dryrun.py) sets XLA_FLAGS before any jax import to provide
-512 virtual host devices.
+Every mesh is built by a function (never a module-level constant), so
+importing this module never touches jax device state.  The CPU
+emulation entry points ask for virtual host devices through
+``emulate_host_devices`` before JAX starts a backend.
 """
 
 from __future__ import annotations
+
+import os
 
 import jax
 
@@ -23,11 +25,30 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(shape, axes)
 
 
-def make_test_mesh(*, multi_pod: bool = True):
-    """8-virtual-device mesh for CI-sized multi-device tests."""
-    shape = (2, 2, 2) if multi_pod else (2, 4)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+def emulate_host_devices(n: int) -> None:
+    """CPU emulation: give XLA's CPU backend ``n`` virtual devices.  A
+    no-op unless ``JAX_PLATFORMS`` pins the CPU, so on an accelerator
+    the mesh is built from the chips that are there.  Call before JAX
+    starts a backend."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip() == "cpu":
+        jax.config.update("jax_num_cpu_devices", n)
+
+
+def local_mesh_shape(n: int) -> tuple[int, int, int]:
+    """(pod, data, model) over ``n`` devices: two pods and two data
+    ranks where ``n`` allows, the rest tensor-parallel — (1,1,1) on one
+    chip, (2,2,1) on four, (2,2,2) on eight."""
+    pod = 2 if n % 2 == 0 else 1
+    data = 2 if (n // pod) % 2 == 0 else 1
+    return pod, data, n // (pod * data)
+
+
+def make_local_mesh(devices=None):
+    """(pod, data, model) mesh over ``devices`` (default: every device
+    JAX sees), shaped by ``local_mesh_shape``."""
+    devices = list(jax.devices() if devices is None else devices)
+    return jax.make_mesh(local_mesh_shape(len(devices)),
+                         ("pod", "data", "model"), devices=devices)
 
 
 def mesh_axis_sizes(mesh) -> dict[str, int]:

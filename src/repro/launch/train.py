@@ -1,50 +1,73 @@
 """End-to-end training driver: data pipeline -> shard_map train step ->
 metrics, with checkpoint/restart, NaN rollback and straggler logging.
 
-CPU-runnable end-to-end:
-    PYTHONPATH=src python -m repro.launch.train --arch qwen2.5-3b --smoke \
-        --steps 100 --mesh test --mode hier
+    PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.train \
+        --arch qwen2.5-3b --smoke --steps 100 --mesh test --mode hier
 
-`--mesh test` uses 8 virtual devices (set before jax import); `--mesh
-none` runs single-device; `--mesh production` is the real 16x16 /
-2x16x16 target (dry-run hardware).
+`--mesh test` is a (pod, data, model) mesh over the devices present:
+(1,1,1) on one chip, (2,2,1) on four, and (2,2,2) over the 8 virtual
+devices XLA's CPU backend provides when ``JAX_PLATFORMS=cpu``.  `--mesh
+none` runs single-device; `--mesh production` is the real 2x16x16
+target (512 virtual CPU devices, dry-run hardware).
 """
 
 import argparse
 import dataclasses
 import os
-import sys
+import pathlib
+import time
 
+import jax
+import numpy as np
 
-def _preparse_mesh() -> str:
-    ap = argparse.ArgumentParser(add_help=False)
-    ap.add_argument("--mesh", default="none")
-    ns, _ = ap.parse_known_args()
-    return ns.mesh
-
-
-_MESH = _preparse_mesh()
-if _MESH == "test":
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8")
-elif _MESH == "production":
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=512")
-
-import time  # noqa: E402
-
-import jax  # noqa: E402
-import numpy as np  # noqa: E402
-
-from repro.configs import get_config  # noqa: E402
-from repro.data import DataConfig, Prefetcher  # noqa: E402
-from repro.launch.mesh import make_production_mesh, make_test_mesh, runtime_for_mesh  # noqa: E402
-from repro.models import Model  # noqa: E402
-from repro.parallel.sharding import Runtime  # noqa: E402
-from repro.runtime import (  # noqa: E402
+from repro.configs import get_config
+from repro.data import DataConfig, Prefetcher
+from repro.launch.mesh import (
+    emulate_host_devices, make_local_mesh, make_production_mesh,
+    runtime_for_mesh)
+from repro.models import Model
+from repro.parallel.sharding import Runtime
+from repro.runtime import (
     CheckpointManager, NaNWatchdog, StragglerMonitor, WatchdogConfig)
-from repro.train import TrainConfig, make_train_step  # noqa: E402
-from repro.train.optimizer import OptConfig  # noqa: E402
+from repro.train import TrainConfig, make_train_step
+from repro.train.optimizer import OptConfig
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> None:
+    """Persistent XLA compilation cache for the drivers; never enabled
+    at import.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
+    uses it and nothing is set here; otherwise the cache lives at the
+    fixed ``<repo>/.jax_cache`` (the path is part of the cache key, so
+    it never moves)."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    jax.config.update("jax_compilation_cache_dir",
+                      str(REPO_ROOT / ".jax_cache"))
+
+
+def init_training(model, tcfg, mesh, seed: int = 0):
+    """make_train_step -> init -> (sharded) build -> ZeRO bootstrap: the
+    library path every driver takes.  Returns ``(step_fn, builder,
+    pshape, params, opt)``; ``builder`` rebuilds the sharded step for
+    the same shapes (None without a mesh)."""
+    builder_or_step, init = make_train_step(model, tcfg, mesh=mesh)
+    params, opt = init(jax.random.key(seed))
+    pshape = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), params)
+    if mesh is None:
+        return builder_or_step, None, pshape, params, opt
+    step_fn, boot = builder_or_step(pshape)
+    if boot is not None:
+        opt = boot(params)
+    return step_fn, builder_or_step, pshape, params, opt
+
+
+def data_config(cfg, global_batch: int, seq: int, seed: int = 0) -> DataConfig:
+    return DataConfig(vocab_size=cfg.vocab_size, global_batch=global_batch,
+                      seq_len=seq, seed=seed, enc_seq=cfg.enc_seq,
+                      d_model=cfg.d_model if cfg.enc_seq else 0)
 
 
 def main(argv=None):
@@ -135,13 +158,17 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.chaos is not None and args.mesh == "none":
         ap.error("--chaos requires a mesh (--mesh test|production)")
+    if args.mesh != "none":
+        # CPU emulation only (a no-op unless JAX_PLATFORMS=cpu)
+        emulate_host_devices(8 if args.mesh == "test" else 512)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.mesh == "none":
         mesh = None
         rt = Runtime(use_pallas=args.pallas)
     else:
-        mesh = (make_test_mesh() if args.mesh == "test"
+        mesh = (make_local_mesh() if args.mesh == "test"
                 else make_production_mesh(multi_pod=True))
         rt = runtime_for_mesh(mesh, fsdp=args.mode == "fsdp",
                               use_pallas=args.pallas)
@@ -329,20 +356,9 @@ def main(argv=None):
                        cluster_weights=cluster_weights,
                        packed=use_packed,
                        opt=OptConfig(lr=args.lr, warmup_steps=20))
-    builder_or_step, init = make_train_step(model, tcfg, mesh=mesh)
-    params, opt = init(jax.random.key(0))
-    if mesh is not None:
-        pshape = jax.tree.map(
-            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), params)
-        step_fn, boot = builder_or_step(pshape)
-        if boot is not None:
-            opt = boot(params)
-    else:
-        step_fn = builder_or_step
-
-    dcfg = DataConfig(vocab_size=cfg.vocab_size, global_batch=args.global_batch,
-                      seq_len=args.seq, enc_seq=cfg.enc_seq,
-                      d_model=cfg.d_model if cfg.enc_seq else 0)
+    step_fn, builder_or_step, pshape, params, opt = init_training(
+        model, tcfg, mesh)
+    dcfg = data_config(cfg, args.global_batch, args.seq)
     ckpt = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
     start = 0
     if ckpt and args.resume and ckpt.latest_step() is not None:

@@ -201,8 +201,7 @@ def _attn_core(q, k, v, cfg: ModelConfig, rt: Runtime, *, causal: bool,
     if rt.use_pallas and kv_len is None and q.shape[1] >= 128:
         from repro.kernels import ops as kops
         return kops.flash_attention(
-            q, k, v, causal=causal, window=window, q_offset=q_offset,
-            interpret=rt.pallas_interpret)
+            q, k, v, causal=causal, window=window, q_offset=q_offset)
     if kv_len is None and k.shape[1] >= CHUNKED_ATTN_MIN_KV:
         return chunked_attention(q, k, v, causal=causal, window=window,
                                  q_offset=q_offset)

@@ -69,8 +69,7 @@ def _split_conv_channels(cfg: ModelConfig, tp: int):
 def _ssd(x, dt, A, B, C, chunk, rt: Runtime, h0=None):
     if rt.use_pallas:
         from repro.kernels import ops as kops
-        return kops.ssd_chunked(x, dt, A, B, C, chunk=chunk, h0=h0,
-                                interpret=rt.pallas_interpret)
+        return kops.ssd_chunked(x, dt, A, B, C, chunk=chunk, h0=h0)
     return kref.ssd_chunked(x, dt, A, B, C, chunk=chunk, h0=h0)
 
 
@@ -98,8 +97,7 @@ def apply_ssm(p, x, cfg: ModelConfig, rt: Runtime, *, chunk: int = 128,
     else:
         if rt.use_pallas:
             from repro.kernels import ops as kops
-            conv = kops.causal_conv1d(conv_in, conv_w, conv_b,
-                                      interpret=rt.pallas_interpret)
+            conv = kops.causal_conv1d(conv_in, conv_w, conv_b)
         else:
             conv = kref.causal_conv1d(conv_in, conv_w, conv_b)
     conv = jax.nn.silu(conv.astype(jnp.float32)).astype(conv_in.dtype)

@@ -46,8 +46,9 @@ class Runtime:
     sp: bool = False                # Megatron sequence parallelism
     remat: bool = True              # activation checkpointing per layer
     remat_policy: str = "none"      # none | save_collectives
-    use_pallas: bool = False        # Pallas kernels (interpret=True on CPU)
-    pallas_interpret: bool = True
+    # Pallas model kernels; interpreted only on the CPU backend
+    # (repro.kernels.pallas_interpret)
+    use_pallas: bool = False
     moe_capacity_factor: float = 1.25
     # MoE expert-parallel dispatch/combine (models/moe.py ep path):
     # the planner-selected All2All schedule mode, the cluster axis of
@@ -69,21 +70,15 @@ class Runtime:
 
 
 # ---------------------------------------------------------------------------
-# shard_map version compat
+# shard_map
 # ---------------------------------------------------------------------------
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions: new jax exposes it at the
-    top level with ``check_vma``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` with the same flag named
-    ``check_rep``.  Every shard_map in this repo goes through here."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    """``jax.shard_map`` with this repo's default ``check_vma=False``
+    (replication is carried by the custom-vjp pairs below, not tracked
+    by JAX).  Every shard_map in this repo goes through here."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 # ---------------------------------------------------------------------------

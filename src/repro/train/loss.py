@@ -15,7 +15,7 @@ from jax import lax
 
 from functools import partial
 
-from repro.parallel.sharding import Runtime
+from repro.parallel.sharding import Runtime, reduce_from_tp
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -50,8 +50,12 @@ def sharded_xent(logits: jax.Array, labels: jax.Array, rt: Runtime,
     local_max = lax.stop_gradient(jnp.max(logits, axis=-1))
     gmax = _pmax_nograd(local_max, rt.tp_axis) if rt.tp_axis else local_max
     sumexp = jnp.sum(jnp.exp(logits - gmax[..., None]), axis=-1)
+    # every TP rank computes the same (replicated) loss from these sums,
+    # so the cotangent of each partial sum is the loss cotangent itself:
+    # psum forward, identity backward (a plain psum would transpose to a
+    # psum and scale every gradient by the TP size)
     if rt.tp_axis:
-        sumexp = lax.psum(sumexp, rt.tp_axis)
+        sumexp = reduce_from_tp(sumexp, rt.tp_axis)
     lse = jnp.log(sumexp) + gmax                        # (B, S)
 
     lbl_local = labels - off
@@ -60,7 +64,7 @@ def sharded_xent(logits: jax.Array, labels: jax.Array, rt: Runtime,
     picked = jnp.take_along_axis(logits, lbl_safe[..., None], axis=-1)[..., 0]
     picked = jnp.where(in_shard, picked, 0.0)
     if rt.tp_axis:
-        picked = lax.psum(picked, rt.tp_axis)
+        picked = reduce_from_tp(picked, rt.tp_axis)
 
     tok_mask = (labels >= 0) & (labels < vocab_size)
     nll = jnp.where(tok_mask, lse - picked, 0.0)

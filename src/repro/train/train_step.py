@@ -240,7 +240,9 @@ def make_train_step(model: Model, tcfg: TrainConfig, mesh=None,
             new_params, new_opt = opt_lib.adam_update(grads, opt_state, params,
                                                       tcfg.opt, clip / n_dp)
 
-        m = {"loss": lval, "grad_norm": gnorm / n_dp, "aux": aux,
+        # gnorm is already the norm of the mean gradient (the synced sum
+        # over n_dp replicas, divided once above)
+        m = {"loss": lval, "grad_norm": gnorm, "aux": aux,
              "mean_logp": metrics["mean_logp"]}
         if dp_axes:
             m = {k: lax.pmean(v, dp_axes) for k, v in m.items()}
@@ -271,29 +273,42 @@ def make_train_step(model: Model, tcfg: TrainConfig, mesh=None,
             return params, None  # bootstrap via make_zero_bootstrap
         return params, opt_lib.adam_init(params)
 
+    donated = (0, 1) if donate else ()
     if mesh is None:
-        specs_const: Any = None
-
         def local_step(params, opt_state, batch):
             specs = jax.tree.map(lambda _: P(), params)
             return step_body(params, opt_state, batch, specs)
 
-        return jax.jit(local_step), init_fn
+        return jax.jit(local_step, donate_argnums=donated), init_fn
 
     # ---------------- sharded wiring ---------------------------------------
-    def build(params_shape):
-        model.prepare(params_shape)
+    def named(spec_tree):
+        return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
+                            is_leaf=lambda s: isinstance(s, P))
+
+    def state_specs(params_shape):
         specs = model.param_specs(params_shape)
-        batch_spec = {"tokens": P(dp_axes or None), "labels": P(dp_axes or None)}
-        if cfg.n_enc_layers:
-            batch_spec["enc"] = P(dp_axes or None)
         if tcfg.comm_mode == "hier_zero1":
             # the flat master varies across both data (scatter) and model
             # (TP shards flattened per column): 2D-shard its only dim.
             zspec = P((ccfg.intra_axis, "model") if rt.tp_axis else ccfg.intra_axis)
-            opt_spec = opt_lib.ZeroState(zspec, zspec, zspec, P())
-        else:
-            opt_spec = opt_lib.AdamState(specs, specs, P())
+            return specs, opt_lib.ZeroState(zspec, zspec, zspec, P())
+        return specs, opt_lib.AdamState(specs, specs, P())
+
+    def sharded_init(key):
+        """init_fn with every leaf created in place on its own shards
+        (never the whole state on one device first)."""
+        specs, opt_spec = state_specs(jax.eval_shape(model.init, key))
+        if tcfg.comm_mode == "hier_zero1" and dp_axes:
+            return jax.jit(model.init, out_shardings=named(specs))(key), None
+        return jax.jit(init_fn, out_shardings=named((specs, opt_spec)))(key)
+
+    def build(params_shape):
+        model.prepare(params_shape)
+        specs, opt_spec = state_specs(params_shape)
+        batch_spec = {"tokens": P(dp_axes or None), "labels": P(dp_axes or None)}
+        if cfg.n_enc_layers:
+            batch_spec["enc"] = P(dp_axes or None)
         metric_spec = {"loss": P(), "grad_norm": P(), "aux": P(),
                        "mean_logp": P()}
 
@@ -303,15 +318,19 @@ def make_train_step(model: Model, tcfg: TrainConfig, mesh=None,
             in_specs=(specs, opt_spec, batch_spec),
             out_specs=(specs, opt_spec, metric_spec),
             check_vma=False)
-        step = jax.jit(fn, donate_argnums=(0, 1) if donate else ())
+        # explicit in/out shardings pin each donated input to the output
+        # leaf with its own spec: left to inference, the donation pairing
+        # can match a sharded input with a same-shaped replicated output
+        step = jax.jit(
+            fn, in_shardings=named((specs, opt_spec, batch_spec)),
+            out_shardings=named((specs, opt_spec, metric_spec)),
+            donate_argnums=donated)
 
         boot = None
         if tcfg.comm_mode == "hier_zero1":
-            zspec = P((ccfg.intra_axis, "model") if rt.tp_axis else ccfg.intra_axis)
             boot = jax.jit(shard_map(
                 zero_bootstrap, mesh=mesh, in_specs=(specs,),
-                out_specs=opt_lib.ZeroState(zspec, zspec, zspec, P()),
-                check_vma=False))
+                out_specs=opt_spec, check_vma=False))
         return step, boot
 
-    return build, init_fn
+    return build, sharded_init

@@ -50,7 +50,10 @@ step_1, _ = make_train_step(model_1, TrainConfig(comm_mode="flat", opt=OPT),
                             mesh=None)
 
 # --- uninterrupted single-device reference ---------------------------------
-p_ref, o_ref = init(jax.random.key(0))
+# the same initial state as plain host values (init places it on its
+# mesh shards, whose sharded types the single-device step cannot take)
+p_ref, o_ref = jax.tree.map(lambda x: jnp.asarray(np.asarray(x)),
+                            init(jax.random.key(0)))
 ref_losses = []
 for i in range(6):
     p_ref, o_ref, m = step_1(p_ref, o_ref, to_batch(i))

@@ -19,6 +19,13 @@ def test_train_comm_modes_8dev():
     _run("check_train_modes.py", timeout=1500)
 
 
+def test_donated_sharded_step_8dev():
+    """Two donated sharded steps of the dense smoke model on the
+    (2,2,2) mesh match the single-device losses and grad norm."""
+    out = _run("check_donated_step.py")
+    assert "OK donated hier+int8 matches single device" in out
+
+
 def test_hlo_analysis_8dev():
     _run("check_hlo_analysis.py")
 
