@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from . import compression, packing, primitives
+from . import compression, packing, primitives, scopes
 from . import schedule as schedule_ir
 
 
@@ -248,8 +248,17 @@ def _exec_step(step: schedule_ir.Step, buf: jax.Array, cfg: CommConfig,
 def _exec_steps(steps, buf: jax.Array, cfg: CommConfig) -> jax.Array:
     ctx = _ExecCtx()
     for step in steps:
-        buf = _exec_step(step, buf, cfg, ctx)
+        # steps that only change ctx emit no op under their scope
+        with scopes.scoped(type(step)):
+            buf = _exec_step(step, buf, cfg, ctx)
     return buf
+
+
+def _flat_psum(x: jax.Array, cfg: CommConfig) -> jax.Array:
+    """The one native all-reduce over every data-parallel axis."""
+    with scopes.scoped(schedule_ir.Flat):
+        return lax.psum(primitives.apply_inject(
+            _apply_cluster_weight(x, cfg), "flat"), cfg.dp_axes)
 
 
 # ---------------------------------------------------------------------------
@@ -267,13 +276,11 @@ def hier_psum(x: jax.Array, cfg: CommConfig) -> jax.Array:
     if cfg.cluster_weights is not None:
         sched = schedule_ir.with_cluster_scale(sched)
     if any(isinstance(s, schedule_ir.Flat) for s in sched.steps):
-        return lax.psum(primitives.apply_inject(
-            _apply_cluster_weight(x, cfg), "flat"), cfg.dp_axes)
+        return _flat_psum(x, cfg)
     if cfg.pod_axis is None and sched.pipelined:
         # Degenerate 1-cluster pipeline: there is no C2C phase to hide,
         # so the chunk loop would only add α costs.  Plain intra psum.
-        return lax.psum(primitives.apply_inject(
-            _apply_cluster_weight(x, cfg), "flat"), cfg.dp_axes)
+        return _flat_psum(x, cfg)
     isize = primitives.axis_size(cfg.intra_axis)
     flat, pad = _pad_to(x.astype(x.dtype), isize)
     out = _exec_steps(sched.steps, flat, cfg)
@@ -295,10 +302,11 @@ def hier_psum_scatter(x: jax.Array, cfg: CommConfig) -> jax.Array:
     if cfg.cluster_weights is not None:
         sched = schedule_ir.with_cluster_scale(sched)
     if any(isinstance(s, schedule_ir.Flat) for s in sched.steps):
-        shard = primitives.hom_reduce_scatter(
-            _apply_cluster_weight(flat, cfg), intra)
-        if cfg.pod_axis is not None:
-            shard = lax.psum(shard, cfg.pod_axis)
+        with scopes.scoped(schedule_ir.Flat):
+            shard = primitives.hom_reduce_scatter(
+                _apply_cluster_weight(flat, cfg), intra)
+            if cfg.pod_axis is not None:
+                shard = lax.psum(shard, cfg.pod_axis)
         return shard
     # the scattered sync is not chunk-pipelined (there is no end phase
     # to overlap): interpret a ChunkLoop body sequentially
@@ -333,12 +341,13 @@ def hier_all_gather(x: jax.Array, cfg: CommConfig, gather_dim: int = 0) -> jax.A
     steps, _ = sched.unrolled()    # the gather path is not chunk-pipelined
     pods = x[None]
     for step in steps:
-        if isinstance(step, schedule_ir.C2CCpy):
-            pods = primitives.c2c_cpy(x, cfg.pod_axis)        # (P, *x), DCN
-        elif isinstance(step, schedule_ir.IntraBcast):
-            pods = lax.all_gather(pods, cfg.intra_axis, axis=0,
-                                  tiled=False)                # (D, P, *x)
-            pods = jnp.swapaxes(pods, 0, 1)                   # (P, D, *x)
+        with scopes.scoped(type(step)):
+            if isinstance(step, schedule_ir.C2CCpy):
+                pods = primitives.c2c_cpy(x, cfg.pod_axis)    # (P, *x), DCN
+            elif isinstance(step, schedule_ir.IntraBcast):
+                pods = lax.all_gather(pods, cfg.intra_axis, axis=0,
+                                      tiled=False)            # (D, P, *x)
+                pods = jnp.swapaxes(pods, 0, 1)               # (P, D, *x)
     alld = jnp.moveaxis(pods, (0, 1), (g, g + 1))             # x[:g],P,D,x[g:]
     P_, D_ = primitives.axis_size(cfg.pod_axis), primitives.axis_size(cfg.intra_axis)
     new_shape = x.shape[:g] + (P_ * D_ * x.shape[g],) + x.shape[g + 1:]
@@ -511,14 +520,18 @@ def tree_hier_psum(tree: Any, cfg: CommConfig, packed: bool = True) -> Any:
     (DESIGN.md §11; asserted by ``tests/mdscripts/check_packed.py``).
     ``packed=False`` keeps the legacy per-step re-flatten for A/B."""
     if not packed:
-        joined, treedef, meta = _bucket(tree)
+        with scopes.scoped(schedule_ir.Pack):
+            joined, treedef, meta = _bucket(tree)
         out = {dt: hier_psum(buf, cfg) for dt, buf in joined.items()}
-        return _unbucket(out, treedef, meta)
+        with scopes.scoped(schedule_ir.Unpack):
+            return _unbucket(out, treedef, meta)
     leaves, treedef = jax.tree.flatten(tree)
     layout, cfgs = _comm_layout_resolved(leaves, cfg)
-    bufs = packing.pack(layout, leaves)
+    with scopes.scoped(schedule_ir.Pack):
+        bufs = packing.pack(layout, leaves)
     out = {dt: hier_psum(buf, cfgs[dt]) for dt, buf in bufs.items()}
-    return jax.tree.unflatten(treedef, packing.unpack(layout, out))
+    with scopes.scoped(schedule_ir.Unpack):
+        return jax.tree.unflatten(treedef, packing.unpack(layout, out))
 
 
 def tree_hier_psum_mean(tree: Any, cfg: CommConfig) -> Any:
@@ -591,7 +604,8 @@ def tree_hier_psum_scatter(tree: Any, cfg: CommConfig) -> tuple[jax.Array, FlatS
     isize = primitives.axis_size(cfg.intra_axis)
     leaves, treedef = jax.tree.flatten(tree)
     layout = _zero1_layout(leaves, isize)
-    bufs = packing.pack(layout, leaves)
+    with scopes.scoped(schedule_ir.Pack):
+        bufs = packing.pack(layout, leaves)
     shards = [hier_psum_scatter(bufs[seg.dtype].astype(jnp.float32), cfg)
               for seg in layout.segments]
     shard = shards[0] if len(shards) == 1 else jnp.concatenate(shards)
@@ -614,13 +628,15 @@ def tree_hier_unscatter(shard: jax.Array, fmeta: FlatShardMeta,
         ssz = seg.padded // isize
         piece = shard[off:off + ssz]
         off += ssz
-        gathered[seg.dtype] = primitives.hom_all_gather(
-            piece.astype(seg.dtype), intra)
+        with scopes.scoped(schedule_ir.IntraAllGather):
+            gathered[seg.dtype] = primitives.hom_all_gather(
+                piece.astype(seg.dtype), intra)
     leaves = []
-    for sl in fmeta.layout.slots:
-        buf = gathered[sl.segment]
-        piece = buf[sl.offset:sl.offset + sl.size].reshape(sl.shape)
-        if str(piece.dtype) != sl.dtype:
-            piece = piece.astype(sl.dtype)
-        leaves.append(piece)
+    with scopes.scoped(schedule_ir.Unpack):
+        for sl in fmeta.layout.slots:
+            buf = gathered[sl.segment]
+            piece = buf[sl.offset:sl.offset + sl.size].reshape(sl.shape)
+            if str(piece.dtype) != sl.dtype:
+                piece = piece.astype(sl.dtype)
+            leaves.append(piece)
     return jax.tree.unflatten(fmeta.treedef, leaves)

@@ -42,6 +42,8 @@ from jax import lax
 
 from repro.kernels import quant as _qk
 
+from . import scopes
+
 BLOCK = _qk.BLOCK          # scale granularity for int8
 _CHUNK = BLOCK             # legacy alias (pre-packing callers)
 
@@ -139,9 +141,13 @@ def compressed_psum(x: jax.Array, axis: str, codec: str,
     device's cluster gradient weight (the deferred ``Scale`` step),
     folded into the codec at zero payload cost (module docstring)."""
     if codec == "bf16":
-        if weight is not None:
-            x = x * jnp.asarray(weight, x.dtype)  # fuses into the cast below
-        return lax.psum(x.astype(jnp.bfloat16), axis).astype(x.dtype)
+        with jax.named_scope(scopes.ENCODE):
+            if weight is not None:
+                x = x * jnp.asarray(weight, x.dtype)  # fuses into the cast
+            enc = x.astype(jnp.bfloat16)
+        summed = lax.psum(enc, axis)
+        with jax.named_scope(scopes.DECODE):
+            return summed.astype(x.dtype)
     if codec == "int8":
         return _int8_psum(x, axis, weight=weight)
     raise ValueError(f"unknown codec {codec!r}")
@@ -157,24 +163,27 @@ def int8_encode(x: jax.Array, axis: str | None,
     ``_int8_psum`` so the pipelined chunk loop can carry the
     pre-quantized next chunk and overlap this stage with the previous
     chunk's ring transfer (``core/pipelined.py``)."""
-    xf, _ = _flat_blocks(x)
-    amax = _block_amax(xf)
-    if weight is not None:
-        # amax(w·x) == w·amax(x) for w > 0: the weighted payload's
-        # shared scale comes from the nb-sized vector, not a payload pass
-        weight = jnp.asarray(weight, jnp.float32)
-        amax = amax * weight
-    scale = _shared_scale(amax, axis)
-    enc_scale = scale if weight is None else scale / weight
-    return _encode_scaled(xf, enc_scale), scale
+    with jax.named_scope(scopes.ENCODE):
+        xf, _ = _flat_blocks(x)
+        amax = _block_amax(xf)
+        if weight is not None:
+            # amax(w·x) == w·amax(x) for w > 0: the weighted payload's
+            # shared scale comes from the nb-sized vector, not a payload
+            # pass
+            weight = jnp.asarray(weight, jnp.float32)
+            amax = amax * weight
+        scale = _shared_scale(amax, axis)
+        enc_scale = scale if weight is None else scale / weight
+        return _encode_scaled(xf, enc_scale), scale
 
 
 def int8_transfer(q: jax.Array, scale: jax.Array, axis: str, size: int,
                   dtype=jnp.float32) -> jax.Array:
     """Transfer stage: int8 reduce ring over ``axis`` + fused decode,
     sliced back to the caller's flat ``size``."""
-    out = _decode(_ring_int8_sum(q, axis), scale)
-    return out[:size].astype(dtype)
+    summed = _ring_int8_sum(q, axis)
+    with jax.named_scope(scopes.DECODE):
+        return _decode(summed, scale)[:size].astype(dtype)
 
 
 def _int8_psum(x: jax.Array, axis: str,

@@ -48,7 +48,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import collectives, packing
+from . import collectives, packing, scopes
+from . import schedule as schedule_ir
 
 # Default per-bucket payload cap.  Large enough that α costs amortize,
 # small enough that the first bucket's sync can start well before the
@@ -274,7 +275,8 @@ def tree_hier_psum_overlap(tree: Any, cfg,
     token = None
     if packed:
         plist, meta, rcs, playout = _packed_bucket_plan(tree, layout, cfg)
-        buf = packing.pack_bucketed(playout, plist)
+        with scopes.scoped(schedule_ir.Pack):
+            buf = packing.pack_bucketed(playout, plist)
         outs = []
         for (start, end), rc in zip(playout.bucket_bounds, rcs):
             seg = _chain(buf[start:end], token)
@@ -285,22 +287,25 @@ def tree_hier_psum_overlap(tree: Any, cfg,
         # bucket's output (bounds are known statically) — no rebuild of
         # the full payload
         starts = [s for s, _ in playout.bucket_bounds]
-        for sl, (key, lo, li, shape, dtype, size) in zip(playout.slots,
-                                                         meta):
-            off = sl.offset - starts[sl.bucket]
-            piece = outs[sl.bucket][off:off + size]
-            pieces[(key, lo, li)] = piece.reshape(shape).astype(dtype)
+        with scopes.scoped(schedule_ir.Unpack):
+            for sl, (key, lo, li, shape, dtype, size) in zip(playout.slots,
+                                                             meta):
+                off = sl.offset - starts[sl.bucket]
+                piece = outs[sl.bucket][off:off + size]
+                pieces[(key, lo, li)] = piece.reshape(shape).astype(dtype)
     else:
         for spec in layout:
-            buf, meta = _bucket_buffer(tree, spec)
+            with scopes.scoped(schedule_ir.Pack):
+                buf, meta = _bucket_buffer(tree, spec)
             buf = _chain(buf, token)
             out = collectives.hier_psum(buf, cfg)
             token = lax.slice_in_dim(out, 0, 1)
             off = 0
-            for key, lo, hi, li, shape, dtype, size in meta:
-                piece = lax.dynamic_slice_in_dim(out, off, size)
-                pieces[(key, lo, li)] = piece.reshape(shape).astype(dtype)
-                off += size
+            with scopes.scoped(schedule_ir.Unpack):
+                for key, lo, hi, li, shape, dtype, size in meta:
+                    piece = lax.dynamic_slice_in_dim(out, off, size)
+                    pieces[(key, lo, li)] = piece.reshape(shape).astype(dtype)
+                    off += size
 
     # ---- reassemble the tree -------------------------------------------
     def rebuild(key: str) -> Any:
@@ -323,5 +328,6 @@ def tree_hier_psum_overlap(tree: Any, cfg,
                 out_leaves.append(jnp.concatenate([p for _, p in runs], axis=0))
         return jax.tree.unflatten(treedef, out_leaves)
 
-    return {key: rebuild(key) if any(k == key for k, _, _ in pieces)
-            else tree[key] for key in tree}
+    with scopes.scoped(schedule_ir.Unpack):
+        return {key: rebuild(key) if any(k == key for k, _, _ in pieces)
+                else tree[key] for key in tree}

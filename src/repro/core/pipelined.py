@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import primitives
+from . import primitives, scopes
 from . import schedule as schedule_ir
 
 
@@ -165,10 +165,17 @@ def pipelined_hier_psum(flat: jax.Array, cfg, use_ring: bool = False,
     # chaos seam: encoded chunks pass through the injection hook on their
     # way onto the DCN — for int8 the hook sees the (q, scale) pair, so
     # bit-flips land in real int8 blocks (identity when no hook installed)
-    _raw_transfer = transfer
+    _raw_encode, _raw_transfer = encode, transfer
+
+    # each chunk's phases carry the IR names, under the ChunkLoop scope
+    # the executor opens
+    def encode(shard):
+        with scopes.scoped(schedule_ir.C2CRed):
+            return _raw_encode(shard)
 
     def transfer(enc):
-        return _raw_transfer(primitives.apply_inject(enc, "chunk_c2c"))
+        with scopes.scoped(schedule_ir.C2CRed):
+            return _raw_transfer(primitives.apply_inject(enc, "chunk_c2c"))
     # One intra ReduceScatter / AllGather on the whole payload: on the
     # emulated backend splitting the ICI phases k-ways buys no overlap
     # (XLA executes the per-device program in order) and pays an extra
@@ -178,9 +185,12 @@ def pipelined_hier_psum(flat: jax.Array, cfg, use_ring: bool = False,
     # the codec (below).  The real-fabric 3-phase overlap is still
     # modeled by the ChunkLoop schedule IR (core/cost_model.py prices
     # all four stages; core/transport_sim.py simulates them).
-    rs = primitives.hom_reduce_scatter(flat, intra)
+    with scopes.scoped(schedule_ir.IntraReduceScatter):
+        rs = primitives.hom_reduce_scatter(flat, intra)
     if k == 1:
-        out = primitives.hom_all_gather(transfer(encode(rs)), intra)
+        red = transfer(encode(rs))
+        with scopes.scoped(schedule_ir.IntraAllGather):
+            out = primitives.hom_all_gather(red, intra)
         return out[:n]
     chunks = rs.reshape(k, chunk)
 
@@ -207,7 +217,8 @@ def pipelined_hier_psum(flat: jax.Array, cfg, use_ring: bool = False,
     out0 = jnp.zeros((shard_n,), flat.dtype)
     (enc_last, red), _ = lax.scan(step, (enc0, out0), jnp.arange(1, k))
     red = write(red, transfer(enc_last), k - 1)   # drain: C2C of chunk k-1
-    out = primitives.hom_all_gather(red, intra)
+    with scopes.scoped(schedule_ir.IntraAllGather):
+        out = primitives.hom_all_gather(red, intra)
     return out[:n]
 
 

@@ -14,7 +14,11 @@ HLO text itself:
      multiplier, and split ICI vs DCN by whether the group crosses pods;
   3. FLOPs are recomputed from dot ops (2 x prod(result) x contracted
      size via a per-computation symbol table) x multiplier; bytes from
-     top-level memory-moving ops (fusion/dot/copy/slice/collective).
+     top-level memory-moving ops (fusion/dot/copy/slice/collective);
+  4. each instruction's ``op_name`` metadata (``op_names``) names the
+     ``jax.named_scope`` it came from (``core/scopes.py``), so each
+     collective's ``wire_bytes_per_chip`` -- the bytes one chip sends
+     per step -- falls to the phase that issued it.
 
 Conventions (documented in EXPERIMENTS.md §Roofline):
   * all-gather:       (g-1)/g * result_bytes per chip
@@ -52,7 +56,10 @@ _DTYPE_BYTES = {
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _DEF_RE = re.compile(r"^(?:ROOT )?%?([\w.\-]+) = (.+?) ([\w\-]+)\(")
-_COMP_HDR_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->\s*.*\{")
+# a computation's header; the signature is left out where the module is
+# printed before optimisation
+_COMP_HDR_RE = re.compile(
+    r"^(ENTRY\s+)?%?([\w.\-]+)\s*(?:\(.*\)\s*->\s*.*)?\{$")
 _PARAM_RE = re.compile(r"%?([\w.\-]+):\s*((?:\([^)]*\))|(?:\w+\[[\d,]*\][^,)]*))")
 _GROUPS_RE = re.compile(r"replica_groups=\{(.*?)\}\}?")
 _GROUPS_IOTA_RE = re.compile(
@@ -62,6 +69,7 @@ _CONST_RE = re.compile(r"%?[\w.\-]+ = s32\[\] constant\((\d+)\)")
 _CALLS_RE = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
 _WHILE_BODY_RE = re.compile(r"body=%?([\w.\-]+)")
 _WHILE_COND_RE = re.compile(r"condition=%?([\w.\-]+)")
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?op_name="((?:[^"\\]|\\.)*)"')
 
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                 "collective-permute")
@@ -122,9 +130,10 @@ def _split_computations(hlo_text: str) -> tuple[dict[str, Computation], str]:
             if m.group(1):
                 entry = cur.name
             # parameters declared in the header carry their types
-            hdr_params = st[st.index("(") + 1:]
-            for pm in _PARAM_RE.finditer(hdr_params):
-                cur.types[pm.group(1)] = pm.group(2)
+            if "(" in st:
+                hdr_params = st[st.index("(") + 1:]
+                for pm in _PARAM_RE.finditer(hdr_params):
+                    cur.types[pm.group(1)] = pm.group(2)
             continue
         if st == "}":
             cur = None
@@ -229,8 +238,77 @@ def _parse_pairs(line: str) -> list[tuple[int, int]]:
             for p in re.findall(r"\{(\d+,\d+)\}", "{" + m.group(1) + "}")]
 
 
+def _op_name(line: str) -> str:
+    m = _OP_NAME_RE.search(line)
+    return m.group(1) if m else ""
+
+
+def _args_end(line: str, start: int) -> int:
+    """Index of the parenthesis closing the one at ``start``."""
+    depth = 0
+    for i, ch in enumerate(line[start:], start):
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth -= 1
+            if depth == 0:
+                return i
+    return len(line) - 1
+
+
+@dataclasses.dataclass
+class Instruction:
+    name: str
+    opcode: str
+    type: str                    # result type
+    operands: list[str]          # instructions of the same computation
+    op_name: str                 # "" where the metadata has none
+    line: str
+
+
+def instructions(hlo_text: str) -> dict[str, Instruction]:
+    """Every instruction of the module, by name.  Instruction names are
+    unique in a module, and the device trace names its ops by them."""
+    comps, _ = _split_computations(hlo_text)
+    out = {}
+    for comp in comps.values():
+        for ln in comp.lines:
+            dm = _DEF_RE.match(ln)
+            if not dm:
+                continue
+            name, rtype, opcode = dm.groups()
+            start = dm.end() - 1
+            inner = ln[start + 1:_args_end(ln, start)]
+            operands = [t for t in re.findall(r"[\w.\-]+", inner)
+                        if t in comp.types and t != name]
+            out[name] = Instruction(name, opcode, rtype, operands,
+                                    _op_name(ln), ln)
+    return out
+
+
+def op_names(hlo_text: str) -> dict[str, str]:
+    """{instruction: the ``op_name`` of its metadata}."""
+    return {n: i.op_name for n, i in instructions(hlo_text).items()}
+
+
+def is_collective(opcode: str) -> bool:
+    return opcode.replace("-start", "") in _COLLECTIVES
+
+
+def collective_key(ins: Instruction, table: dict[str, Instruction]):
+    """What a compiler rewrite of a collective keeps: its replica groups
+    and the shape of its first operand (a reduce-scatter rewritten as an
+    all-reduce and a dynamic-slice keeps both)."""
+    groups = tuple(tuple(g) for g in _parse_groups(ins.line, 0))
+    src = table.get(ins.operands[0]) if ins.operands else None
+    shape = _SHAPE_RE.findall(src.type) if src else []
+    return groups, tuple(shape[:1])
+
+
 @dataclasses.dataclass
 class CollectiveOp:
+    name: str                    # the HLO instruction
+    op_name: str                 # its metadata's op_name (named scopes)
     kind: str
     result_bytes: int
     group_size: int
@@ -281,7 +359,8 @@ def analyze_module(hlo_text: str, n_devices: int, pod_size: int,
                                   for s, t in pairs)
                     wire = float(rb) * k_mult
                     colls.append(CollectiveOp(
-                        base_kind, rb, 2, crosses, 2 if crosses else 1,
+                        opname, _op_name(ln), base_kind, rb, 2, crosses,
+                        2 if crosses else 1,
                         k_mult, wire, wire if crosses else 0.0,
                         0.0 if crosses else wire, ln[:160]))
                 else:
@@ -304,8 +383,9 @@ def analyze_module(hlo_text: str, n_devices: int, pod_size: int,
                         ici = wire - dcn
                     else:
                         dcn, ici = 0.0, wire
-                    colls.append(CollectiveOp(base_kind, rb, g, crosses, pods,
-                                              k_mult, wire, dcn, ici, ln[:160]))
+                    colls.append(CollectiveOp(opname, _op_name(ln), base_kind,
+                                              rb, g, crosses, pods, k_mult,
+                                              wire, dcn, ici, ln[:160]))
                 bytes_ += 2.0 * rb * k_mult
                 continue
 
@@ -345,16 +425,7 @@ def _op_bytes(line: str, rtype: str, comp: Computation) -> float:
         return 0.0
     total = float(_type_bytes(rtype))
     start = line.index("(")
-    depth, end = 0, len(line) - 1
-    for i, ch in enumerate(line[start:], start):
-        if ch == "(":
-            depth += 1
-        elif ch == ")":
-            depth -= 1
-            if depth == 0:
-                end = i
-                break
-    inner = line[start + 1:end]
+    inner = line[start + 1:_args_end(line, start)]
     for m in re.finditer(r"%([\w.\-]+)", inner):
         t = comp.types.get(m.group(1))
         if t:

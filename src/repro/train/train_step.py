@@ -48,6 +48,7 @@ from repro.core import collectives as coll
 from repro.core.collectives import CommConfig
 from repro.core import compression
 from repro.core import overlap as overlap_lib
+from repro.core import scopes
 from repro.core.schedule import STRUCTURAL_MODES, build_schedule
 from repro.models.model import Model
 from repro.parallel.sharding import Runtime, shard_map
@@ -155,15 +156,58 @@ def make_train_step(model: Model, tcfg: TrainConfig, mesh=None,
             n = n * lax.psum(1, ax)
         return n
 
+    def sync_grads(grads, specs):
+        """The all-reduce gradient sync of every mode but hier_zero1."""
+        if tcfg.comm_mode == "fsdp":
+            # fsdp leaves arrive reduce-scattered over data (the
+            # autodiff transpose of the per-layer all_gather = the
+            # start homColl); the only explicit sync left is the
+            # pod-axis c2cRed (+ optional int8/bf16 compression).
+            def sync(g, s):
+                if _spec_has(s, "data"):
+                    if rt.pod_axis is None:
+                        return g
+                    w = None
+                    if tcfg.cluster_weights is not None:
+                        # the autodiff transpose already did the intra
+                        # RS; the weight is constant within a pod, so
+                        # scaling here is still the exact uneven-shard
+                        # weighted reduction
+                        w = jnp.asarray(tcfg.cluster_weights, jnp.float32)[
+                            lax.axis_index(rt.pod_axis)]
+                    if tcfg.dcn_compression:
+                        # weight folds into the codec's scale vector
+                        # (zero payload-sized HBM traffic)
+                        return compression.compressed_psum(
+                            g, rt.pod_axis, tcfg.dcn_compression, weight=w)
+                    if w is not None:
+                        g = g * w.astype(g.dtype)
+                    return lax.psum(g, rt.pod_axis)
+                return coll.hier_psum(g, ccfg) if dp_axes else g
+            return jax.tree.map(sync, grads, specs)
+        if tcfg.comm_mode == "hier_overlap" and dp_axes:
+            # readiness-ordered bucket chain: XLA may overlap each
+            # bucket's C2C with the backward ops still producing later
+            # buckets (core/overlap.py)
+            return overlap_lib.tree_hier_psum_overlap(
+                grads, ccfg, cap_bytes=tcfg.bucket_cap_mb << 20,
+                packed=tcfg.packed)
+        if dp_axes:
+            return coll.tree_hier_psum(grads, ccfg, packed=tcfg.packed)
+        return grads
+
     # ---------------- the shard-local step body ---------------------------
     def step_body(params, opt_state, batch, specs):
         tokens, labels = batch["tokens"], batch["labels"]
         enc = batch.get("enc")
 
         def loss_fn(p):
-            logits, aux = model.apply_train(p, tokens, enc)
-            l, metrics = loss_lib.sharded_xent(logits, labels, rt,
-                                               cfg.vocab_size, tcfg.z_loss)
+            # inside value_and_grad, so that the backward reads
+            # transpose(jvp(forward)) and the two stay apart in the HLO
+            with jax.named_scope(scopes.FORWARD):
+                logits, aux = model.apply_train(p, tokens, enc)
+                l, metrics = loss_lib.sharded_xent(
+                    logits, labels, rt, cfg.vocab_size, tcfg.z_loss)
             return l + tcfg.aux_weight * aux, (metrics, aux)
 
         (lval, (metrics, aux)), grads = jax.value_and_grad(
@@ -175,7 +219,8 @@ def make_train_step(model: Model, tcfg: TrainConfig, mesh=None,
             # AllReduceH with the end-AllGather fused into the parameter
             # reconstruction (ZeRO-1): RS(ICI) -> c2cRed(DCN) gives the
             # synced f32 shard that feeds Adam directly.
-            shard, fmeta = coll.tree_hier_psum_scatter(grads, ccfg)
+            with jax.named_scope(scopes.SYNC):
+                shard, fmeta = coll.tree_hier_psum_scatter(grads, ccfg)
             # (the packed master layout groups leaves by wire dtype so
             # the sync and the reconstruction gather below run bf16
             # segments at 2 bytes/elem — collectives.FlatShardMeta)
@@ -184,78 +229,47 @@ def make_train_step(model: Model, tcfg: TrainConfig, mesh=None,
             # and are over-counted x tp — documented approximation;
             # crucially identical on every device, so clipping stays
             # consistent.
-            sq = jnp.sum(shard.astype(jnp.float32) ** 2)
-            sq = lax.psum(sq, ccfg.intra_axis)
-            if rt.tp_axis:
-                sq = lax.psum(sq, rt.tp_axis)
-            gnorm = jnp.sqrt(sq) / n_dp
-            clip = jnp.minimum(1.0, tcfg.opt.grad_clip / (gnorm + 1e-9))
-            zstate = opt_lib.zero_update(shard, opt_state, tcfg.opt,
-                                         clip / n_dp)
-            new_params = coll.tree_hier_unscatter(zstate.flat_param, fmeta,
-                                                  ccfg)
+            with jax.named_scope(scopes.OPTIMIZER):
+                sq = jnp.sum(shard.astype(jnp.float32) ** 2)
+                sq = lax.psum(sq, ccfg.intra_axis)
+                if rt.tp_axis:
+                    sq = lax.psum(sq, rt.tp_axis)
+                gnorm = jnp.sqrt(sq) / n_dp
+                clip = jnp.minimum(1.0, tcfg.opt.grad_clip / (gnorm + 1e-9))
+                zstate = opt_lib.zero_update(shard, opt_state, tcfg.opt,
+                                             clip / n_dp)
+            with jax.named_scope(scopes.SYNC):
+                new_params = coll.tree_hier_unscatter(zstate.flat_param,
+                                                      fmeta, ccfg)
             new_opt = zstate
         else:
-            if tcfg.comm_mode == "fsdp":
-                # fsdp leaves arrive reduce-scattered over data (the
-                # autodiff transpose of the per-layer all_gather = the
-                # start homColl); the only explicit sync left is the
-                # pod-axis c2cRed (+ optional int8/bf16 compression).
-                def sync(g, s):
-                    if _spec_has(s, "data"):
-                        if rt.pod_axis is None:
-                            return g
-                        w = None
-                        if tcfg.cluster_weights is not None:
-                            # the autodiff transpose already did the
-                            # intra RS; the weight is constant within a
-                            # pod, so scaling here is still the exact
-                            # uneven-shard weighted reduction
-                            w = jnp.asarray(tcfg.cluster_weights,
-                                            jnp.float32)[
-                                lax.axis_index(rt.pod_axis)]
-                        if tcfg.dcn_compression:
-                            # weight folds into the codec's scale vector
-                            # (zero payload-sized HBM traffic)
-                            return compression.compressed_psum(
-                                g, rt.pod_axis, tcfg.dcn_compression,
-                                weight=w)
-                        if w is not None:
-                            g = g * w.astype(g.dtype)
-                        return lax.psum(g, rt.pod_axis)
-                    return coll.hier_psum(g, ccfg) if dp_axes else g
-                grads = jax.tree.map(sync, grads, specs)
-            elif tcfg.comm_mode == "hier_overlap" and dp_axes:
-                # readiness-ordered bucket chain: XLA may overlap each
-                # bucket's C2C with the backward ops still producing
-                # later buckets (core/overlap.py)
-                grads = overlap_lib.tree_hier_psum_overlap(
-                    grads, ccfg, cap_bytes=tcfg.bucket_cap_mb << 20,
-                    packed=tcfg.packed)
-            elif dp_axes:
-                grads = coll.tree_hier_psum(grads, ccfg,
-                                            packed=tcfg.packed)
-            gnorm = _global_grad_norm(grads, specs, rt) / n_dp
-            clip = jnp.minimum(1.0, tcfg.opt.grad_clip / (gnorm + 1e-9))
-            new_params, new_opt = opt_lib.adam_update(grads, opt_state, params,
-                                                      tcfg.opt, clip / n_dp)
+            with jax.named_scope(scopes.SYNC):
+                grads = sync_grads(grads, specs)
+            with jax.named_scope(scopes.OPTIMIZER):
+                gnorm = _global_grad_norm(grads, specs, rt) / n_dp
+                clip = jnp.minimum(1.0,
+                                   tcfg.opt.grad_clip / (gnorm + 1e-9))
+                new_params, new_opt = opt_lib.adam_update(
+                    grads, opt_state, params, tcfg.opt, clip / n_dp)
 
         # gnorm is already the norm of the mean gradient (the synced sum
         # over n_dp replicas, divided once above)
         m = {"loss": lval, "grad_norm": gnorm, "aux": aux,
              "mean_logp": metrics["mean_logp"]}
         if dp_axes:
-            m = {k: lax.pmean(v, dp_axes) for k, v in m.items()}
+            with jax.named_scope(scopes.STEP_METRICS):
+                m = {k: lax.pmean(v, dp_axes) for k, v in m.items()}
         if tcfg.finite_gate:
             # see TrainConfig.finite_gate: poisoned updates become
             # no-ops so donated buffers still carry the usable state.
             # The gate keys off the *reduced* scalars (a local-only NaN
             # would gate one shard and desync the others).
-            ok = jnp.isfinite(m["loss"]) & jnp.isfinite(m["grad_norm"])
-            new_params = jax.tree.map(
-                lambda n, o: jnp.where(ok, n, o), new_params, params)
-            new_opt = jax.tree.map(
-                lambda n, o: jnp.where(ok, n, o), new_opt, opt_state)
+            with jax.named_scope(scopes.OPTIMIZER):
+                ok = jnp.isfinite(m["loss"]) & jnp.isfinite(m["grad_norm"])
+                new_params = jax.tree.map(
+                    lambda n, o: jnp.where(ok, n, o), new_params, params)
+                new_opt = jax.tree.map(
+                    lambda n, o: jnp.where(ok, n, o), new_opt, opt_state)
         return new_params, new_opt, m
 
     # ---------------- init ------------------------------------------------
