@@ -29,6 +29,13 @@ Layout rules:
     the pod count).  Downstream code paths keep their legacy padding
     branches for unpacked callers, but on a packed buffer every one of
     them is a no-op.
+  * **whole reduce-scatter spans** — a segment synced across chips and
+    large enough that it costs under 1/64 of it is padded on to a
+    multiple of ``world * RS_SPAN`` too (``padded_size``).  The TPU
+    v5e compiler's reduce-scatter over two chips moves each shard in
+    spans of 4864 rows of 128 lanes: a shard of whole spans stays one
+    plain ``reduce-scatter``, any other it pads itself, as a fusion,
+    then mends the shifted shard with a permute and two copies of it.
   * **bucket slices** — the overlap scheduler's readiness-ordered
     buckets are *aligned contiguous slices of the one packed buffer*
     (``PackedLayout.bucket_bounds``), replacing the per-bucket
@@ -43,12 +50,19 @@ executors only.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Sequence
 
 # Block granularity of the int8 wire codec (== kernels.quant.BLOCK;
 # duplicated as a plain int so the layout math stays importable without
 # jax — tests assert the two constants agree).
 DEFAULT_BLOCK = 1024
+
+# Elements a span of the TPU's reduce-scatter emitter moves: 4864 rows of
+# the 128 lanes ``primitives.hom_reduce_scatter`` scatters by.
+RS_SPAN = 4864 * 128
+# Segments of fewer than this many padding units keep the plain alignment.
+_RS_SPAN_MIN = 64
 
 _ITEMSIZE = {
     "float32": 4, "float64": 8, "bfloat16": 2, "float16": 2,
@@ -83,6 +97,18 @@ def comm_alignment(world: int, n_chunks: int = 1,
     each factor is needed).  ``block`` should be ``DEFAULT_BLOCK`` when
     the int8 codec may run and 1 otherwise."""
     return max(1, int(world)) * max(1, int(n_chunks)) * max(1, int(block))
+
+
+def padded_size(used: int, align: int, world: int = 1) -> int:
+    """Padded extent of a segment of ``used`` elements: a multiple of
+    ``align`` and, where ``world > 1`` and the segment holds at least
+    ``_RS_SPAN_MIN`` units of ``lcm(align, world * RS_SPAN)``, of that
+    unit, so that every reduce-scatter shard is whole spans."""
+    if world > 1:
+        unit = math.lcm(max(1, int(align)), world * RS_SPAN)
+        if used >= _RS_SPAN_MIN * unit:
+            return aligned_size(used, unit)
+    return aligned_size(used, align)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -196,7 +222,8 @@ def plan_layout(metas: Sequence[tuple[str, tuple, int]], *,
     Leaves are grouped into one segment per wire dtype, preserving
     their relative order; each segment is padded to the comm alignment
     (``align_for(dtype, used)`` overrides the default
-    ``comm_alignment(world, n_chunks, block)`` per segment)."""
+    ``comm_alignment(world, n_chunks, block)`` per segment), and a large
+    one to whole reduce-scatter spans (``padded_size``)."""
     default_align = comm_alignment(world, n_chunks, block)
     order: list[str] = []
     used: dict[str, int] = {}
@@ -211,7 +238,8 @@ def plan_layout(metas: Sequence[tuple[str, tuple, int]], *,
     segments = []
     for dt in order:
         a = align_for(dt, used[dt]) if align_for is not None else default_align
-        segments.append(Segment(dt, used[dt], aligned_size(used[dt], a)))
+        segments.append(Segment(dt, used[dt],
+                                padded_size(used[dt], a, world)))
     # `align` records the weakest guarantee across segments (validate()
     # checks each segment against it)
     align = default_align if align_for is None else _gcd_all(
@@ -222,7 +250,6 @@ def plan_layout(metas: Sequence[tuple[str, tuple, int]], *,
 
 
 def _gcd_all(xs: Sequence[int]) -> int:
-    import math
     g = 0
     for x in xs:
         g = math.gcd(g, int(x))

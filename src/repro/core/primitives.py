@@ -82,7 +82,31 @@ def hom_all_gather(x: jax.Array, axis, gather_dim: int = 0) -> jax.Array:
     return lax.all_gather(x, axis, axis=gather_dim, tiled=True)
 
 
+# A 1-D operand is reduce-scattered as rows of ``RS_LANES``: on the TPU a
+# 1-D array is stored in tiles of 8 x 128 elements, so a ``(rows, 128)``
+# view whose shards are whole tiles is a bitcast of it, both ways.
+RS_LANES = 128
+_RS_TILE = 8 * RS_LANES
+
+
 def hom_reduce_scatter(x: jax.Array, axis, scatter_dim: int = 0) -> jax.Array:
+    """Tiled reduce-scatter over ``axis``.
+
+    XLA's TPU compiler lowers a reduce-scatter of a 1-D operand as an
+    all-reduce of the whole operand and a slice, twice the wire bytes of
+    a reduce-scatter; a 2-D ``(rows, RS_LANES)`` operand keeps a true
+    one.  So a 1-D operand whose length divides by ``n * 1024`` (``n``
+    the axis size, above 1) is scattered as rows: split row-major,
+    device ``i``'s rows are elements ``[i*len/n, (i+1)*len/n)``, the
+    1-D shard.  Any other operand, and an axis of size 1, go as they
+    are.  A shard that is not whole spans of the compiler's emitter
+    (``packing.RS_SPAN``) it pads and mends itself, in a fusion; the
+    packed layout pads large buffers to whole spans."""
+    n = axis_size(axis)
+    if x.ndim == 1 and n > 1 and x.shape[0] % (n * _RS_TILE) == 0:
+        rows = lax.psum_scatter(x.reshape(-1, RS_LANES), axis,
+                                scatter_dimension=0, tiled=True)
+        return rows.reshape(-1)
     return lax.psum_scatter(x, axis, scatter_dimension=scatter_dim, tiled=True)
 
 

@@ -23,7 +23,9 @@ HLO text itself:
 Conventions (documented in EXPERIMENTS.md §Roofline):
   * all-gather:       (g-1)/g * result_bytes per chip
   * all-reduce:       2*(g-1)/g * result_bytes per chip
-  * reduce-scatter:   (g-1)   * result_bytes per chip (= (g-1)/g * input)
+  * reduce-scatter:   (g-1)   * result_bytes per chip (= (g-1)/g * input);
+    also a fusion calling ``all-reduce-scatter``, the TPU compiler's
+    form of a reduce-scatter whose operand it pads
   * all-to-all:       (g-1)/g * result_bytes per chip
   * collective-permute: result_bytes per chip
   * a flat collective spanning P pods is attributed (P-1)/P of its bytes
@@ -91,9 +93,13 @@ def _type_bytes(typestr: str) -> int:
 
 
 def _last_shape_bytes(typestr: str) -> int:
+    """Bytes of the last array of an async start's tuple (its result;
+    the scalar contexts a collective-permute-start appends are left
+    out where it has an array of rank 1 or more)."""
     ms = list(_SHAPE_RE.finditer(typestr))
     if not ms:
         return 0
+    ms = [m for m in ms if m.group(2)] or ms
     m = ms[-1]
     n = 1
     if m.group(2):
@@ -207,6 +213,19 @@ def _fused_comps(comps: dict[str, Computation]) -> set[str]:
                 for cm in re.finditer(r"to_apply=%?([\w.\-]+)", ln):
                     fused.add(cm.group(1))
     return fused
+
+
+def _fused_reduce_scatter(line: str, comps: dict[str, Computation]):
+    """The all-reduce line of a fusion the TPU compiler made of a
+    reduce-scatter it pads (it calls ``all-reduce-scatter``: an
+    all-reduce and a dynamic-slice, run as one reduce-scatter), else
+    None."""
+    cm = _CALLS_RE.search(line)
+    if cm is None or not cm.group(1).startswith("all-reduce-scatter"):
+        return None
+    body = comps.get(cm.group(1))
+    return next((ln for ln in body.lines if " all-reduce(" in ln),
+                None) if body else None
 
 
 def _parse_groups(line: str, n_devices: int) -> list[list[int]]:
@@ -350,6 +369,11 @@ def analyze_module(hlo_text: str, n_devices: int, pod_size: int,
                 continue
             opname, rtype, opkind = dm.groups()
             base_kind = opkind.replace("-start", "")
+            group_line = ln
+            if opkind == "fusion":
+                inner = _fused_reduce_scatter(ln, comps)
+                if inner is not None:
+                    base_kind, group_line = "reduce-scatter", inner
             if base_kind in _COLLECTIVES and not opkind.endswith("-done"):
                 rb = (_last_shape_bytes(rtype) if opkind.endswith("-start")
                       else _type_bytes(rtype))
@@ -364,7 +388,7 @@ def analyze_module(hlo_text: str, n_devices: int, pod_size: int,
                         k_mult, wire, wire if crosses else 0.0,
                         0.0 if crosses else wire, ln[:160]))
                 else:
-                    groups = _parse_groups(ln, n_devices)
+                    groups = _parse_groups(group_line, n_devices)
                     g = max(len(grp) for grp in groups)
                     pods = max(len({d // pod_size for d in grp})
                                for grp in groups)

@@ -1,9 +1,15 @@
 """Multi-device validation of the HetCCL core collectives.
 
-Run as a subprocess by tests/test_collectives_multidevice.py with 8
-virtual CPU devices arranged as (pod=2, data=2, model=2).  Every
-hierarchical collective is checked against its flat native reference;
-prints one OK line per check and exits nonzero on any mismatch.
+Run as a subprocess by tests/test_multidevice.py with 8 virtual CPU
+devices arranged as (pod=2, data=2, model=2).  Every hierarchical
+collective is checked against its flat native reference; prints one OK
+line per check and exits nonzero on any mismatch.
+
+The reduce-scatters run at two widths: an odd one, and one whose buffer
+``primitives.hom_reduce_scatter`` scatters as ``(rows, RS_LANES)`` (a
+length that divides by ``n * 1024``).  The last section checks the
+primitive's values against the 1-D ``lax.psum_scatter`` and where its
+row view shows in the lowered module.
 """
 
 import os
@@ -11,6 +17,7 @@ import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import functools  # noqa: E402
+import re  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -40,6 +47,11 @@ def check(name, got, want, atol=1e-5):
 
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.normal(size=(NDEV, 37)).astype(np.float32))  # odd width
+# each (pod, data) device holds 2 rows: 12288 elements, whole rows for the
+# intra scatter (n = 2) and, on its 6144-element shard, for the pod one
+WIDE = 2 * 1024 * 3
+x_wide = jnp.asarray(rng.normal(size=(NDEV, WIDE)).astype(np.float32))
+WIDTHS = (("", x), (f",w={WIDE}", x_wide))
 
 
 # --- c2c primitives --------------------------------------------------------
@@ -68,18 +80,25 @@ check("c2c_bcast", np.asarray(got), want)
 
 # --- hier_psum vs flat psum -------------------------------------------------
 
-flat_want = np.asarray(
-    run(lambda v: lax.psum(v, ("pod", "data")), x,
-        P(("pod", "data"), None), P(None))
-)
-for mode, nch, codec in [("hier", 1, None), ("hier_pipelined", 3, None),
-                         ("hier", 1, "bf16"), ("hier_pipelined", 2, "bf16")]:
-    cfg = CommConfig(mode=mode, pod_axis="pod", intra_axis="data",
-                     n_chunks=nch, compression=codec)
-    got = run(lambda v: collectives.hier_psum(v, cfg), x,
-              P(("pod", "data"), None), P(None))
-    atol = 1e-5 if codec is None else 0.15
-    check(f"hier_psum[{mode},k={nch},codec={codec}]", got, flat_want, atol)
+def psum_want(v):
+    return np.asarray(run(lambda t: lax.psum(t, ("pod", "data")), v,
+                          P(("pod", "data"), None), P(None)))
+
+
+flat_want = psum_want(x)
+for tag, xw in WIDTHS:
+    want = psum_want(xw)
+    for mode, nch, codec in [("hier", 1, None), ("hier_pipelined", 3, None),
+                             ("hier", 1, "bf16"),
+                             ("hier_pipelined", 2, "bf16"),
+                             ("hier_border_rs", 1, None)]:
+        cfg = CommConfig(mode=mode, pod_axis="pod", intra_axis="data",
+                         n_chunks=nch, compression=codec)
+        got = run(lambda v: collectives.hier_psum(v, cfg), xw,
+                  P(("pod", "data"), None), P(None))
+        atol = 1e-5 if codec is None else 0.15
+        check(f"hier_psum[{mode},k={nch},codec={codec}{tag}]", got, want,
+              atol)
 
 # int8 compressed psum
 cfg = CommConfig(mode="hier", compression="int8")
@@ -92,12 +111,19 @@ print("OK hier_psum[int8] mean-rel", float(rel_plain.mean()))
 
 # --- hier_psum_scatter + unscatter round trip -------------------------------
 
-cfg = CommConfig(mode="hier")
-def rs_then_ag(v):
+def rs_then_ag(v, cfg):
     shard = collectives.hier_psum_scatter(v.reshape(-1), cfg)
     return collectives.hier_all_gather_flat(shard, cfg, v.size).reshape(v.shape)
-got = run(rs_then_ag, x, P(("pod", "data"), None), P(None))
-check("hier_psum_scatter->all_gather", got, flat_want)
+
+
+for tag, xw in WIDTHS:
+    # "flat" takes the ZeRO-1 Flat branch: one scatter over the intra axis
+    for mode in ("hier", "flat"):
+        cfg = CommConfig(mode=mode)
+        got = run(functools.partial(rs_then_ag, cfg=cfg), xw,
+                  P(("pod", "data"), None), P(None))
+        name = "" if (mode, tag) == ("hier", "") else f"[{mode}{tag}]"
+        check(f"hier_psum_scatter->all_gather{name}", got, psum_want(xw))
 
 
 # --- hier_all_gather vs flat all_gather --------------------------------------
@@ -177,5 +203,78 @@ assert rel.mean() <= rel_noef.mean() * 1.05, (
     f"error feedback should not hurt: {rel.mean()} vs {rel_noef.mean()}")
 print("OK psum_ef[int8] two-step mean-rel", float(rel.mean()),
       "(no-EF:", float(rel_noef.mean()), ")")
+
+
+# --- hom_reduce_scatter's row view -------------------------------------------
+
+# "one" is an axis of size 1, as on a one-chip mesh
+mesh1 = jax.make_mesh((2, 1, 4), ("pod", "one", "dp"))
+SHORT = 2 * 1000                   # divides by n, not by n * 1024
+
+
+def plain_rs(v, axis):
+    """The 1-D scatter, as the primitive was before its row view."""
+    return lax.psum_scatter(v, axis, scatter_dimension=0, tiled=True)
+
+
+def scatter(fn, m, axis, v):
+    """``fn(row, axis)`` on each device's own row of ``v``; every
+    device's result, stacked in device order."""
+    names = m.axis_names
+    f = shard_map(lambda t: fn(t[0], axis)[None], mesh=m,
+                  in_specs=P(names), out_specs=P(names), check_vma=False)
+    return np.asarray(jax.jit(f)(v).astype(jnp.float32))
+
+
+for dt in ("float32", "bfloat16"):
+    for case, m, axis, length in (("qualifying", mesh, "data", WIDE),
+                                  ("not-multiple", mesh, "data", SHORT),
+                                  ("axis-size-1", mesh1, "one", WIDE)):
+        v = jnp.asarray(rng.normal(size=(m.size, length)), dt)
+        got = scatter(primitives.hom_reduce_scatter, m, axis, v)
+        # a sum of two values does not depend on its order: exact
+        np.testing.assert_array_equal(got, scatter(plain_rs, m, axis, v),
+                                      err_msg=case)
+        print(f"OK hom_reduce_scatter[{case},{dt}]")
+
+RS_RE = re.compile(r'"stablehlo\.reduce_scatter".*?\}\) : '
+                   r"\(tensor<([\dx]+)x(\w+)>\) -> tensor<([\dx]+)x\w+>",
+                   re.S)
+
+
+def lowered_hier(m, intra, length, rs):
+    """StableHLO of ``hier_psum`` over (pod, ``intra``) on a 1-D buffer
+    of ``length``, with ``rs`` as the reduce-scatter primitive."""
+    cfg = CommConfig(mode="hier", pod_axis="pod", intra_axis=intra)
+    names = m.axis_names
+    f = jax.jit(shard_map(lambda v: collectives.hier_psum(v[0], cfg)[None],
+                          mesh=m, in_specs=P(names), out_specs=P(names),
+                          check_vma=False))
+    saved = primitives.hom_reduce_scatter
+    primitives.hom_reduce_scatter = rs
+    try:
+        return f.lower(jnp.zeros((m.size, length), jnp.float32)).as_text()
+    finally:
+        primitives.hom_reduce_scatter = saved
+
+
+shapes = RS_RE.findall(lowered_hier(mesh, "data", WIDE,
+                                    primitives.hom_reduce_scatter))
+assert len(shapes) == 1, shapes
+operand, _, result = shapes[0]
+dims = [int(d) for d in operand.split("x")]
+assert dims == [WIDE // primitives.RS_LANES, primitives.RS_LANES], dims
+assert [int(d) for d in result.split("x")] == [dims[0] // 2, dims[1]], result
+print("OK lowering[qualifying-rows]")
+
+for case, m, intra, length in (("not-multiple", mesh, "data", SHORT),
+                               ("axis-size-1", mesh1, "one", WIDE)):
+    text = lowered_hier(m, intra, length, primitives.hom_reduce_scatter)
+    shapes = RS_RE.findall(text)
+    assert len(shapes) == 1 and "x" not in shapes[0][0], (case, shapes)
+    # the module is the one the plain 1-D scatter lowers to: no reshape
+    # was added around the collective
+    assert text == lowered_hier(m, intra, length, plain_rs), case
+    print(f"OK lowering[{case}]")
 
 print("ALL-OK")

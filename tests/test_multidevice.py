@@ -6,10 +6,47 @@ import pytest
 from _mdrun import run_mdscript as _run
 
 
-def test_hetccl_collectives_8dev():
+@pytest.fixture(scope="module")
+def collectives_out():
+    return _run("check_collectives.py")
+
+
+def test_hetccl_collectives_8dev(collectives_out):
     """c2c primitives + every hierarchical collective vs flat natives."""
-    out = _run("check_collectives.py")
-    assert "hier_psum[hier_pipelined" in out
+    assert "hier_psum[hier_pipelined" in collectives_out
+
+
+W = ",w=6144"
+
+
+@pytest.mark.parametrize("case", [
+    f"hier_psum[{m}{W}]" for m in (
+        "hier,k=1,codec=None", "hier_pipelined,k=3,codec=None",
+        "hier,k=1,codec=bf16", "hier_pipelined,k=2,codec=bf16",
+        "hier_border_rs,k=1,codec=None")] + [
+    f"hier_psum_scatter->all_gather[{m}{W}]" for m in ("hier", "flat")])
+def test_row_view_callers_8dev(collectives_out, case):
+    """Every caller of ``hom_reduce_scatter`` on a buffer it scatters
+    as rows (intra RS, chunk loop, border-RS pod leg, ZeRO-1 and its
+    Flat branch) equals the native psum."""
+    assert f"OK {case}\n" in collectives_out
+
+
+@pytest.mark.parametrize("case", [
+    f"{c},{dt}" for dt in ("float32", "bfloat16")
+    for c in ("qualifying", "not-multiple", "axis-size-1")])
+def test_reduce_scatter_values_8dev(collectives_out, case):
+    """The row view gives the 1-D ``psum_scatter``'s shard, exactly,
+    for a length of whole rows, one that is not, and an axis of size 1."""
+    assert f"OK hom_reduce_scatter[{case}]\n" in collectives_out
+
+
+@pytest.mark.parametrize("case", ["qualifying-rows", "not-multiple",
+                                  "axis-size-1"])
+def test_reduce_scatter_lowering_8dev(collectives_out, case):
+    """A qualifying buffer reaches the collective as (rows, 128); any
+    other, and an axis of size 1, lower as the plain 1-D scatter."""
+    assert f"OK lowering[{case}]\n" in collectives_out
 
 
 @pytest.mark.slow

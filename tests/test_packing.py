@@ -309,6 +309,36 @@ def test_comm_alignment_floor():
         assert a % (world * k) == 0 and a % block == 0
 
 
+# OLMo-1B's float32 gradient at 8 of its 16 layers, in elements
+OLMO_USED = 639_893_504
+
+
+@pytest.mark.parametrize("world,n_chunks,block", [(4, 1, 1), (2, 1, 1),
+                                                  (4, 3, 1024)])
+def test_large_segment_padded_to_whole_spans(world, n_chunks, block):
+    """A large segment synced across chips keeps its comm alignment and
+    is padded on so that every intra shard is whole reduce-scatter
+    spans, for less than 1/64 of it."""
+    seg = packing.plan_layout([("float32", (OLMO_USED,), OLMO_USED)],
+                              world=world, n_chunks=n_chunks,
+                              block=block).segments[0]
+    assert seg.padded % packing.comm_alignment(world, n_chunks, block) == 0
+    for n in (2, world):
+        assert (seg.padded // n) % packing.RS_SPAN == 0
+    assert 0 < seg.padded - seg.used < seg.used / 64
+
+
+def test_whole_spans_only_where_synced_and_large():
+    # the four-chip cell: 512 rows of 128 more on each of two shards
+    assert packing.padded_size(OLMO_USED, 4, world=4) == 640_024_576
+    # one chip: the alignment alone, so the one-chip step is unchanged
+    assert packing.padded_size(OLMO_USED, 1, world=1) == OLMO_USED
+    # under 64 units of world * RS_SPAN: the alignment alone
+    unit = 4 * packing.RS_SPAN
+    assert packing.padded_size(64 * unit - 4, 4, world=4) == 64 * unit - 4
+    assert packing.padded_size(64 * unit + 4, 4, world=4) == 65 * unit
+
+
 # ---------------------------------------------------------------------------
 # Elastic shard remap (remap_shard_ops / apply_remap_ops)
 # ---------------------------------------------------------------------------

@@ -118,6 +118,51 @@ def test_collective_names_and_op_names():
     assert by["psum.2"].wire_bytes_per_chip == 2 * (2 - 1) / 2 * 16
 
 
+# The TPU compiler's form of a reduce-scatter whose operand it pads: a
+# fusion calling ``all-reduce-scatter`` (an all-reduce and a slice run
+# as one reduce-scatter), then a collective-permute that mends the
+# shifted shard, whose start tuple ends in scalar contexts.
+PADDED_RS = f"""HloModule jit_step, is_scheduled=true
+
+%region_0 (a: f32[], b: f32[]) -> f32[] {{
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %add.1 = f32[] add(f32[] %a, f32[] %b)
+}}
+
+%all-reduce-scatter (input: f32[30,128]) -> f32[16,128] {{
+  %input = f32[30,128]{{1,0}} parameter(0)
+  %constant.1 = f32[] constant(0)
+  %pad.1 = f32[32,128]{{1,0}} pad(%input, %constant.1), padding=0_2x0_0
+  %all-reduce.2 = f32[32,128]{{1,0}} all-reduce(%pad.1), channel_id=3, replica_groups={{{{0,1}},{{2,3}}}}, use_global_device_ids=true, to_apply=%region_0
+  %c = u32[] constant(0)
+  ROOT %dynamic-slice.3 = f32[16,128]{{1,0}} dynamic-slice(%all-reduce.2, %c, %c), dynamic_slice_sizes={{16,128}}
+}}
+
+ENTRY %main (param.1: f32[30,128]) -> f32[16,128] {{
+  %param.1 = f32[30,128]{{1,0}} parameter(0)
+  %fusion.17 = f32[16,128]{{1,0}} fusion(%param.1), kind=kCustom, calls=%all-reduce-scatter, metadata={{op_name="{J}/sync/IntraReduceScatter/reduce_scatter"}}
+  %slice.5 = f32[1,128]{{1,0}} slice(%fusion.17), slice={{[15:16], [0:128]}}
+  %collective-permute-start = (f32[1,128]{{1,0}}, f32[1,128]{{1,0}}, u32[], u32[]) collective-permute-start(%slice.5), channel_id=4, source_target_pairs={{{{0,1}},{{2,3}}}}
+  %collective-permute-done = f32[1,128]{{1,0}} collective-permute-done(%collective-permute-start)
+  ROOT %concatenate.1 = f32[16,128]{{1,0}} concatenate(%collective-permute-done, %fusion.17), dimensions={{0}}
+}}
+"""
+
+
+def test_padded_reduce_scatter_is_a_reduce_scatter():
+    costs = ha.analyze_module(PADDED_RS, 4, pod_size=2)
+    by = {c.name: c for c in costs.collectives}
+    # the fusion's all-reduce is not counted apart from it
+    assert set(by) == {"fusion.17", "collective-permute-start"}
+    rs = by["fusion.17"]
+    assert (rs.kind, rs.group_size) == ("reduce-scatter", 2)
+    assert rs.wire_bytes_per_chip == (2 - 1) * 16 * 128 * 4
+    assert rs.op_name == f"{J}/sync/IntraReduceScatter/reduce_scatter"
+    # the permute's bytes are its f32[1,128], not the scalar context
+    assert by["collective-permute-start"].wire_bytes_per_chip == 128 * 4
+
+
 def test_phase_map_places_what_the_compiler_made():
     pm = scopes.phase_map(OPTIMISED, LOWERED)
     # the rewritten reduce-scatter: its all-reduce by the lowered

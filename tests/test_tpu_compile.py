@@ -1,8 +1,10 @@
 """Compile-only checks for the TPU: the int8 codec kernels at a real
 gradient width (one 2048x11008 qwen2.5-3b MLP weight) and flash
 attention at qwen2.5-3b widths, compiled for one chip of a described
-v5e:2x2 slice.  Nothing runs; a pass says the TPU compiler accepts the
-kernels (tiling, VMEM) and emits them as Mosaic custom calls.
+v5e:2x2 slice, and the packed gradient's reduce-scatter over two of its
+chips.  Nothing runs; a pass says the TPU compiler accepts the kernels
+(tiling, VMEM) and emits them as Mosaic custom calls, and keeps a
+whole-span reduce-scatter a plain one.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and test workers each import
@@ -13,11 +15,15 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
+from repro.core import packing, primitives
 from repro.kernels import ops
 from repro.kernels import quant as qk
+from repro.parallel.sharding import shard_map
 
 N = 2048 * 11008          # one real-width gradient buffer
 NB = N // qk.BLOCK
@@ -84,3 +90,27 @@ def test_flash_attention_forward_compiles_for_v5e(one_chip):
     compiled = jax.jit(lambda q, k, v: ops.flash_attention(
         q, k, v, causal=True, interpret=False)).lower(q, kv, kv).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 1
+
+
+@pytest.fixture(scope="module")
+def pod_data(topo):
+    return Mesh(np.array(topo.devices).reshape(2, 2), ("pod", "data"))
+
+
+@pytest.mark.parametrize("whole_spans", [True, False])
+def test_packed_reduce_scatter_on_v5e(pod_data, whole_spans):
+    """OLMo-1B's packed float32 gradient (the four-chip cell's), as the
+    layout pads it, reduce-scatters over the two chips of ``data`` as
+    one plain ``reduce-scatter``; unpadded, the compiler pads it in an
+    ``all-reduce-scatter`` fusion and mends the shard with a permute."""
+    used = 639_893_504
+    n = packing.padded_size(used, 4, world=4) if whole_spans else used
+    f = shard_map(lambda v: primitives.hom_reduce_scatter(v, "data"),
+                  mesh=pod_data, in_specs=P(), out_specs=P("data"),
+                  check_vma=False)
+    x = jax.ShapeDtypeStruct((n,), jnp.float32,
+                             sharding=NamedSharding(pod_data, P()))
+    text = jax.jit(f).lower(x).compile().as_text()
+    assert (" reduce-scatter(" in text) == whole_spans
+    assert ("calls=%all-reduce-scatter" in text) != whole_spans
+    assert ("collective-permute" in text) != whole_spans
