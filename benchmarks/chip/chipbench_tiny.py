@@ -5,7 +5,8 @@ plant in the program underneath a run.
 the published configuration's shape kinds (olmo-1b's non-parametric
 LayerNorm and MHA, or qwen2.5-3b's RMSNorm, GQA and QKV bias) at widths
 a test can hold, the traffic mix of the real cells at 2 x 64 tokens a
-chip, and the limits the tests hold it to.
+chip, and the limits the tests hold it to; the benchmark's reference
+and FLOP modules beside them, as in a checkout.
 """
 
 from __future__ import annotations
@@ -30,12 +31,18 @@ TINY_LIMITS = {"loss_gap": 2e-4, "grad_norm_gap": 1e-4,
 
 
 def make_root(dst: pathlib.Path, kind: str = "olmo", chips: int = 1,
-              dtype: str = "float32", seq: int = 64) -> pathlib.Path:
+              dtype: str = "float32", seq: int = 64,
+              config: dict | None = None,
+              traffic: dict | None = None) -> pathlib.Path:
+    """``config``, ``traffic``: keys added to (or replacing those of) the
+    tiny configuration and traffic mix."""
     home = dst / "benchmarks" / "chip"
     for d in ("configs", "traffic", "limits"):
         (home / d).mkdir(parents=True, exist_ok=True)
     shutil.copytree(HERE / "metrics", home / "metrics", dirs_exist_ok=True,
                     ignore=shutil.ignore_patterns("__pycache__"))
+    for f in ("reference.py", "flops.py"):
+        shutil.copy(HERE / f, home / f)
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     src = "olmo-1b-fp32" if kind == "olmo" else "qwen2.5-3b-fp32"
     cfg = json.loads((HERE / "configs" / f"{src}.json").read_text())
@@ -43,13 +50,14 @@ def make_root(dst: pathlib.Path, kind: str = "olmo", chips: int = 1,
                num_attention_heads=4,
                num_key_value_heads=4 if kind == "olmo" else 2,
                intermediate_size=128, vocab_size=256, dtype=dtype)
+    cfg.update(config or {})
     (home / "configs" / "tiny.json").write_text(json.dumps(cfg))
-    traffic = json.loads((HERE / "traffic" / "seq2k-b2.1chip.json")
-                         .read_text())
-    traffic.update(seq_len=seq, seqs_per_chip=2, trace_steps=2)
-    traffic["mesh"] = ({"pod": 2, "data": 2, "model": 1} if chips == 4
-                       else {"pod": 1, "data": 1, "model": 1})
-    (home / "traffic" / "tiny.json").write_text(json.dumps(traffic))
+    mix = json.loads((HERE / "traffic" / "seq2k-b2.1chip.json").read_text())
+    mix.update(seq_len=seq, seqs_per_chip=2, trace_steps=2)
+    mix["mesh"] = ({"pod": 2, "data": 2, "model": 1} if chips == 4
+                   else {"pod": 1, "data": 1, "model": 1})
+    mix.update(traffic or {})
+    (home / "traffic" / "tiny.json").write_text(json.dumps(mix))
     (home / "limits" / "tiny.tiny.json").write_text(json.dumps(TINY_LIMITS))
     bench["configs"] = [{"name": "tiny", "source": "x",
                          "file": "benchmarks/chip/configs/tiny.json",
@@ -71,7 +79,6 @@ def plant(fault: str, patch) -> None:
     import jax.numpy as jnp
 
     import harness
-    import reference
 
     if fault == "unchanged":
         # the step returns the state it was given
@@ -119,6 +126,7 @@ def plant(fault: str, patch) -> None:
         # the program's own model one precision below the configuration's
         class Lower(harness.Program):
             def __init__(self, cell, devices):
+                reference = harness.resolve(cell.home, cell.config).reference
                 low = reference.LOWER[cell.config["dtype"]]
                 super().__init__(dataclasses.replace(
                     cell, config=dict(cell.config, dtype=low)), devices)

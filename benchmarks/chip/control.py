@@ -8,9 +8,10 @@ For each of ``--seeds``, the program's first three steps through the
 same path a run takes (``harness.Program.first_steps``) against the
 reference: the lower readings.  For each of ``--control-seeds``, the
 control (the program's own model in the precision below the
-configuration's, ``reference.LOWER``) and the planted faults the cell
-can have (half of the batch left out; on several chips, the gradient
-exchange left out), each against the reference: the upper readings;
+configuration's, ``LOWER`` of the configuration's reference module) and
+the planted faults the cell can have (half of the batch left out; on
+several chips, the gradient exchange left out), each against the
+reference: the upper readings;
 and the reference put in the program's place in mixed precision
 (computed in that lower precision, parameters and Adam in float32), a
 path the program does not have.  A step that returns its
@@ -33,7 +34,6 @@ sys.path[:0] = [str(HERE), str(HERE.parents[1] / "src")]
 
 import compare  # noqa: E402
 import harness  # noqa: E402
-import reference  # noqa: E402
 import tokens  # noqa: E402
 
 def batches(cell, seed):
@@ -82,6 +82,7 @@ def main(argv=None) -> int:
                                        reference=theirs)) + "\n")
             dump.flush()
 
+    reference = harness.resolve(cell.home, cell.config).reference
     ref = reference.Reference(cell.config, opt, devices)
     prog = harness.Program(cell, devices)
     for seed in seeds:

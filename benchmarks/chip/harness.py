@@ -4,8 +4,12 @@ A cell is one entry of ``workloads`` in ``BENCHMARK.json``: a model
 configuration (``configs/<config>.json``) under a traffic mix
 (``traffic/<traffic>.json``), with the limits of its correctness check
 (``limits/<cell>.json``).  Its per-layer metrics are read by
-``metrics/<metric>.py``.  Everything is found by name, so a new cell,
-configuration, traffic mix or metric is a new file and no edit here.
+``metrics/<metric>.py``.  A configuration may name what it brings of its
+own (``resolve``): fields of the program's ``ModelConfig`` under
+``"program"``, its plain reference module under ``"reference"`` and its
+FLOP count under ``"flops"``.  Everything is found by name, so a new
+cell, configuration, traffic mix or metric is a new file and no edit
+here.
 
 A run builds the program's training step through its library path
 (``Model`` -> ``make_train_step``) for the cell's mesh and compiles it;
@@ -14,9 +18,11 @@ the step through its first three steps, whose readings the correctness
 check compares with the plain reference; then measures a window of
 ``seconds`` in a closed loop that keeps one step in flight, each step
 on a fresh batch from the benchmark's own prefetch thread.  With a
-trace, a few steps are traced first, in a segment of their own.  After
-the window the program's state is freed and the reference
-(``reference.py``) repeats the first three steps.
+trace, a few steps are traced first, in a segment of their own, and
+after the window every compiled instruction is given the program's
+named scope, so that the readers get each scope's device time.  After
+the window the program's state is freed and the configuration's
+reference repeats the first three steps.
 """
 
 from __future__ import annotations
@@ -30,6 +36,7 @@ import math
 import os
 import pathlib
 import queue
+import re
 import resource
 import shutil
 import statistics
@@ -37,11 +44,11 @@ import sys
 import tempfile
 import threading
 import time
+import types
 
 import compare
-import flops
 import peaks as peaks_lib
-import reference
+import scopemap
 import tokens
 import tracereduce
 
@@ -49,7 +56,13 @@ BENCH = pathlib.Path(__file__).resolve().parent
 REPO = BENCH.parents[1]
 FIRST_STEPS = 3
 SPANS = ("batch_wait", "dispatch", "wait_metrics")
+WINDOW = "traced_window"
 GIB = 1 << 30
+# what a configuration's reference and FLOP modules provide
+REFERENCE_API = ("abstract_params", "init_params", "seed_key",
+                 "leaf_norms", "change_norms", "LOWER", "Reference")
+FLOPS_API = ("train_flops_per_token",)
+_STEM = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 
 
 class SetupError(RuntimeError):
@@ -71,6 +84,7 @@ class Cell:
     limits: dict
     end_to_end: list
     per_layer: list        # [(metric entry, path of its reader)]
+    home: pathlib.Path     # the benchmark's own directory
 
     @property
     def rows(self) -> int:
@@ -120,15 +134,20 @@ def load_cell(root: pathlib.Path, workload: str) -> Cell:
         traffic=_read_json(home / "traffic" / f"{w['traffic']}.json",
                            "traffic"),
         limits=_read_json(home / "limits" / f"{workload}.json", "limits"),
-        end_to_end=e2e, per_layer=layer)
+        end_to_end=e2e, per_layer=layer, home=home)
+
+
+def load_module(path: pathlib.Path, kind: str) -> types.ModuleType:
+    """The module in ``path``, loaded by its file path."""
+    name = f"chip_{kind}_{path.stem.replace('.', '_').replace('-', '_')}"
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reader(path: pathlib.Path):
-    spec = importlib.util.spec_from_file_location(
-        f"chip_metric_{path.stem.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(path, "metric").read
 
 
 # --------------------------------------------------------- the program --
@@ -145,16 +164,64 @@ _FIELDS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
 
 
 def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
+    """The program's ``ModelConfig`` for a configuration file: its
+    published keys translated by ``_FIELDS``, then the fields its
+    ``"program"`` section sets, which win; ``family`` is ``"dense"``
+    unless that section says otherwise."""
     import jax.numpy as jnp
     from repro.configs.base import ModelConfig
 
     if cfg.get("norm_eps", 1e-5) != 1e-5:
         raise SetupError("the program's norms use eps 1e-5; the "
                          f"configuration states {cfg['norm_eps']}")
+    program = cfg.get("program", {})
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    unknown = sorted(set(program) - fields)
+    if unknown:
+        raise SetupError(f"{cfg['name']}: 'program' sets {unknown}, no "
+                         f"field of the program's ModelConfig")
+    # the precision is the configuration's own "dtype", which the
+    # control lowers; the name is the file's
+    fixed = sorted({"name", "dtype"} & set(program))
+    if fixed:
+        raise SetupError(f"{cfg['name']}: 'program' may not set {fixed}")
     kw = {field: cfg[key] for key, field in _FIELDS.items() if key in cfg}
-    return ModelConfig(name=cfg["name"], family="dense",
-                       dtype=jnp.dtype(cfg["dtype"]), **kw)
+    kw = {"family": "dense", **kw, **program}
+    return ModelConfig(name=cfg["name"], dtype=jnp.dtype(cfg["dtype"]), **kw)
+
+
+@dataclasses.dataclass(frozen=True)
+class Parts:
+    """What a configuration runs with: the program's ``ModelConfig``, the
+    plain reference module (``REFERENCE_API``; ``reference.py`` says what
+    each name does) and the module that counts the model's FLOPs."""
+    model: object
+    reference: types.ModuleType
+    flops: types.ModuleType
+
+
+def _part(home: pathlib.Path, cfg: dict, key: str, api: tuple):
+    stem = cfg.get(key, key)
+    if not isinstance(stem, str) or not _STEM.fullmatch(stem):
+        raise SetupError(f"{cfg['name']}: {key!r} names no module: {stem!r}")
+    path = home / f"{stem}.py"
+    if not path.is_file():
+        raise SetupError(f"{cfg['name']}: no {key} module {path}")
+    mod = load_module(path, key)
+    missing = [a for a in api if not hasattr(mod, a)]
+    if missing:
+        raise SetupError(f"{cfg['name']}: {key} module {path} lacks "
+                         f"{missing}")
+    return mod
+
+
+def resolve(home: pathlib.Path, cfg: dict) -> Parts:
+    """The parts a configuration names, found under ``home``: its
+    ``"program"`` fields, its ``"reference"`` module (default
+    ``reference``) and its ``"flops"`` module (default ``flops``)."""
+    return Parts(model=model_config(cfg),
+                 reference=_part(home, cfg, "reference", REFERENCE_API),
+                 flops=_part(home, cfg, "flops", FLOPS_API))
 
 
 def opt_config(traffic: dict):
@@ -231,9 +298,11 @@ class Program:
         t = cell.traffic
         m = t["mesh"]
         self.cell = cell
+        self.parts = resolve(cell.home, cell.config)
+        reference = self.parts.reference
         self.mesh = jax.make_mesh((m["pod"], m["data"], m["model"]),
                                   ("pod", "data", "model"), devices=devices)
-        model = Model(model_config(cell.config), runtime_for_mesh(self.mesh))
+        model = Model(self.parts.model, runtime_for_mesh(self.mesh))
         tcfg = TrainConfig(comm_mode=t["comm_mode"],
                            dcn_compression=t["compression"],
                            packed=t["packed"], n_chunks=t["n_chunks"],
@@ -274,10 +343,19 @@ class Program:
         bshape = {k: jax.ShapeDtypeStruct((rows, seq), jax.numpy.int32,
                                           sharding=self.batch_sharding)
                   for k in ("tokens", "labels")}
-        self.compiled = step_fn.lower(abstract(pshape, psh),
-                                      abstract(opt_shape, osh),
-                                      bshape).compile()
+        # the module before optimisation names the scope of each
+        # collective that the compiler rewrites without its metadata
+        self.lowered = step_fn.lower(abstract(pshape, psh),
+                                     abstract(opt_shape, osh), bshape)
+        self.compiled = self.lowered.compile()
         self.step = self.compiled
+
+    def labels(self) -> dict:
+        """{instruction: named scope} of the compiled step
+        (``scopemap.labels``); the instructions of no scope left out."""
+        return scopemap.labels(
+            self.compiled.as_text(),
+            self.lowered.as_text(dialect="hlo", debug_info=True))
 
     def memory_analysis_bytes(self) -> int | None:
         """Per-device bytes the compiled step needs: arguments + outputs
@@ -289,7 +367,7 @@ class Program:
                 - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
 
     def state(self, seed: int):
-        return self._make(reference.seed_key(seed))
+        return self._make(self.parts.reference.seed_key(seed))
 
     def first_steps(self, state, feed: Feed, opt_cfg: dict,
                     n: int = FIRST_STEPS):
@@ -311,7 +389,7 @@ class Program:
                            / (float(m["grad_norm"]) + 1e-9))
                 grad_norms = {k: float(v) / (1.0 - opt_cfg["b1"]) / clip
                               for k, v in self._norms(opt.mu).items()}
-        p0 = self._p0(reference.seed_key(feed.seed))
+        p0 = self._p0(self.parts.reference.seed_key(feed.seed))
         change = {k: float(v) for k, v in self._change(params, p0).items()}
         del p0
         return (params, opt), {"losses": losses, "grad_norms": grad_norms,
@@ -421,10 +499,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         gc.collect()
         gc.freeze()
         setup_s = time.perf_counter() - t_start
-        reduced = None
+        tr = reduced = None
         if trace:
-            state, reduced = traced_segment(prog, state, feed,
-                                            t["trace_steps"])
+            state, tr, reduced = traced_segment(prog, state, feed,
+                                                t["trace_steps"])
         gc.disable()
         before = resource.getrusage(resource.RUSAGE_SELF)
         try:
@@ -456,9 +534,18 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     log(f"[memory] peak_bytes_in_use {peak_used} B, compiled step "
         f"{compiled} B: peak_hbm_gib from "
         f"{'the compiled step' if compiled >= peak_used else 'peak_bytes_in_use'}")
-    del prog
+    phases = None
+    if reduced is not None:
+        t0 = time.perf_counter()
+        phases = tracereduce.by_phase(tr, prog.labels(), WINDOW,
+                                      t["trace_steps"])["phase_s_per_step"]
+        log(f"[scopes] labelled and reduced in "
+            f"{time.perf_counter() - t0:.2f} s; ms a step: " + ", ".join(
+                f"{k} {1e3 * v:.3f}" for k, v in phases.items()))
+    parts = prog.parts
+    del prog, tr
 
-    ref = reference.Reference(cell.config, t["optimizer"], devices)
+    ref = parts.reference.Reference(cell.config, t["optimizer"], devices)
     batches = [tokens.batch(seed, s, cell.rows, t["seq_len"],
                             cell.config["vocab_size"], t["generator"]["zipf_a"])
                for s in range(FIRST_STEPS)]
@@ -477,7 +564,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
               "device": {"platform": dev.platform, "kind": dev.device_kind,
                          "count": len(devices), "memory_peak_bytes": peak}}
     if trace:
-        ctx = metric_context(cell, win, reduced, dev.device_kind)
+        ctx = metric_context(cell, parts, win, reduced, phases,
+                             dev.device_kind)
         metrics = {}
         for m, path in cell.per_layer:
             value = load_reader(path)(ctx)
@@ -501,9 +589,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
 
 
 def traced_segment(prog: Program, state, feed: Feed, steps: int):
-    """Trace ``steps`` steps of the loop in a segment of their own and
-    reduce the trace; returns the state and the reduction (None when the
-    trace holds no device op)."""
+    """Trace ``steps`` steps of the loop in a segment of their own;
+    returns the state, the loaded trace and its reduction (both None
+    when the profiler wrote no trace; the reduction None when the trace
+    holds no device op)."""
     import jax
 
     tdir = tempfile.mkdtemp(prefix="chip-trace-")
@@ -513,28 +602,34 @@ def traced_segment(prog: Program, state, feed: Feed, steps: int):
         opts.host_tracer_level = 1
         jax.profiler.start_trace(tdir, profiler_options=opts)
         try:
-            with jax.profiler.TraceAnnotation("traced_window"):
+            with jax.profiler.TraceAnnotation(WINDOW):
                 state, _ = loop(prog.step, state, feed, steps=steps,
                                 annotate=True)
         finally:
             jax.profiler.stop_trace()
         files = sorted(pathlib.Path(tdir).rglob("*.xplane.pb"))
-        reduced = None
-        if files:
-            tr = tracereduce.load(str(files[-1]), SPANS + ("traced_window",))
-            reduced = tracereduce.reduce(tr, "traced_window", steps)
-        return state, reduced
+        if not files:
+            return state, None, None
+        tr = tracereduce.load(str(files[-1]), SPANS + (WINDOW,))
+        return state, tr, tracereduce.reduce(tr, WINDOW, steps)
     finally:
         shutil.rmtree(tdir, ignore_errors=True)
 
 
-def metric_context(cell: Cell, win: dict, reduced, device_kind: str):
+def metric_context(cell: Cell, parts: Parts, win: dict, reduced, phases,
+                   device_kind: str):
     """What the per-layer readers read: the measured window, the reduced
-    trace (or None), and the yardstick's FLOPs and peaks."""
+    trace and each named scope's device seconds a step (``a/b`` counting
+    for ``a`` too; both None when the trace holds no device op), the
+    cell's configuration and traffic, and the yardstick's FLOPs and
+    peaks."""
     return {"window": win,
             "trace": reduced,
+            "phases": phases,
+            "config": cell.config,
+            "traffic": cell.traffic,
             "chips": cell.chips,
-            "flops_per_token": flops.train_flops_per_token(
+            "flops_per_token": parts.flops.train_flops_per_token(
                 cell.config, cell.traffic["seq_len"]),
             "peak": peaks_lib.peaks(device_kind)}
 

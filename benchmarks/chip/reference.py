@@ -26,6 +26,20 @@ Adam kept in float32 (mixed precision); ``fault`` plants a fault in the
 reference put in the program's place: ``"half_batch"`` drops the second
 half of each device's tokens from the loss and gradient,
 ``"no_exchange"`` leaves the gradient sum across devices out.
+
+This is the default reference of a configuration.  One that names its
+own (``"reference": "<stem>"`` in its file; ``harness.resolve``) names
+a module ``<stem>.py`` beside this one that provides the same names:
+``abstract_params(cfg)`` (the parameter tree as shapes, which the
+program's must equal), ``init_params(cfg, key)`` (the weights from a
+key, in the configuration's dtype; jitted, run on the device),
+``seed_key(seed)``, ``leaf_norms(tree)`` and ``change_norms(new, old)``
+({leaf name: float32 norm}; jitted), ``LOWER`` ({the configuration's
+dtype: the precision of the control}) and ``Reference(cfg, opt,
+devices, fault=None, compute="float32")``, whose ``.run(seed, batches)``
+returns the first steps' ``losses``, ``grad_norms`` and
+``change_norms`` as ``Reference.run`` below does, with the faults
+``"half_batch"`` and ``"no_exchange"`` and the lower ``compute``.
 """
 
 from __future__ import annotations

@@ -1,7 +1,9 @@
 """The four-chip path on 4 virtual CPU devices, in a child process: the
 program's synced gradient on a (pod, data) = (2, 2) mesh equals the
 reference's over the same rows, a sound run is correct, and one with
-the gradient exchange left out is not."""
+the gradient exchange left out is not; under the flat sync too, which
+also fails with its state left unchanged or half of its batch left
+out."""
 
 import json
 import os
@@ -47,6 +49,27 @@ out["loss"] = [float(m["loss"]), float(loss)]
 out["grad_err"] = max(float(np.max(np.abs(a - c * clip)) / np.max(np.abs(c * clip)))
                       for a, c in zip(synced, g))
 out["sound"] = ct.run(root, seed=12)["correct"]
+
+flat = {"comm_mode": "flat"}
+out["flat_sound"] = ct.run(ct.make_root(pathlib.Path(sys.argv[1]) / "f", "qwen",
+                                        4, traffic=flat), seed=13)["correct"]
+for fault in ("unchanged", "half_batch", "no_exchange"):
+    undo = []
+
+    def patch(obj, name, value):
+        undo.append((obj, name, getattr(obj, name)))
+        setattr(obj, name, value)
+
+    ct.plant(fault, patch)
+    try:
+        res = ct.run(ct.make_root(pathlib.Path(sys.argv[1]) / f"f-{fault}",
+                                  "qwen", 4, traffic=flat), seed=13)
+    finally:
+        for obj, name, value in reversed(undo):
+            setattr(obj, name, value)
+    out[f"flat_{fault}"] = [res["correct"], max(
+        c["value"] / c["limit"] for c in res["checks"].values())]
+
 ct.plant("no_exchange", setattr)
 res = ct.run(ct.make_root(pathlib.Path(sys.argv[1]) / "b", "qwen", 4,
                           dtype="float32"), seed=12)
@@ -78,3 +101,11 @@ def test_four_chip_run_correct_and_exchange_fault_caught(four):
     assert four["sound"]
     assert not four["no_exchange"]
     assert four["no_exchange_margin"] > 3
+
+
+def test_flat_run_correct_and_faults_caught(four):
+    assert four["flat_sound"]
+    for fault in ("unchanged", "half_batch", "no_exchange"):
+        correct, margin = four[f"flat_{fault}"]
+        assert not correct, fault
+        assert margin > 3, fault

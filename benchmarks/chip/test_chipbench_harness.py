@@ -34,8 +34,10 @@ def test_every_cell_resolves(workload):
         assert callable(harness.load_reader(reader))
     sync = {m["name"] for m, _ in cell.per_layer if m["layer"] ==
             "gradient sync"}
-    assert sync == ({"sync_collective_ms", "sync_exposed_ms"}
-                    if cell.chips == 4 else set())
+    assert sync == ({"sync_collective_ms", "sync_exposed_ms", "sync_pack_ms"}
+                    if cell.chips == 4 else {"sync_pack_ms"})
+    scoped = {"forward_ms", "backward_ms", "optimizer_ms", "sync_pack_ms"}
+    assert scoped <= {m["name"] for m, _ in cell.per_layer}
 
 
 @pytest.mark.parametrize("path", sorted((HERE / "configs").glob("*.json")),
@@ -61,6 +63,82 @@ def test_config_files_state_their_cut(path):
     for f in ("d_model", "n_heads", "n_kv_heads", "d_ff", "vocab_size",
               "norm", "qkv_bias", "tie_embeddings", "rope_theta"):
         assert getattr(mine, f) == getattr(full, f), f
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
+def test_every_config_resolves_its_parts(entry):
+    """A configuration that names none of its parts runs the dense
+    program with the benchmark's own reference and FLOP count."""
+    import flops
+    import reference
+
+    cfg = json.loads((REPO / entry["file"]).read_text())
+    parts = harness.resolve(HERE, cfg)
+    assert parts.model == harness.model_config(cfg)
+    assert parts.model.family == "dense"
+    assert pathlib.Path(parts.reference.__file__) == HERE / "reference.py"
+    assert pathlib.Path(parts.flops.__file__) == HERE / "flops.py"
+    assert set(harness.REFERENCE_API) <= set(vars(parts.reference))
+    assert parts.reference.LOWER == reference.LOWER
+    assert parts.flops.train_flops_per_token(cfg, 2048) == \
+        flops.train_flops_per_token(cfg, 2048)
+
+
+def _olmo() -> dict:
+    return json.loads((HERE / "configs" / "olmo-1b-fp32.json").read_text())
+
+
+def test_program_section_reaches_model_config():
+    cfg = dict(_olmo(), program={"family": "moe", "n_experts": 4, "top_k": 2,
+                                 "moe_d_ff": 64, "d_ff": 96})
+    mine = harness.model_config(cfg)
+    assert (mine.family, mine.n_experts, mine.top_k, mine.moe_d_ff) == (
+        "moe", 4, 2, 64)
+    # the section wins over the published key it shares a field with
+    assert mine.d_ff == 96 and cfg["intermediate_size"] == 8192
+    assert mine.d_model == cfg["hidden_size"]
+    assert mine.dtype == harness.model_config(_olmo()).dtype
+
+
+@pytest.mark.parametrize("program", [{"n_expert": 4}, {"family": "moe",
+                                                       "topk": 2},
+                                     {"dtype": "bfloat16"}, {"name": "x"}],
+                         ids=["typo", "typo-beside-a-field", "dtype", "name"])
+def test_program_section_unknown_key_is_setup_error(program):
+    with pytest.raises(harness.SetupError) as e:
+        harness.model_config(dict(_olmo(), program=program))
+    assert sorted(program)[-1] in str(e.value)
+
+
+@pytest.mark.parametrize("key,stem", [("reference", "no_such_reference"),
+                                      ("flops", "no_such_flops"),
+                                      ("reference", "../reference"),
+                                      ("flops", "tokens")],
+                         ids=["no-reference", "no-flops", "outside",
+                              "lacks-its-function"])
+def test_missing_part_is_setup_error(key, stem):
+    with pytest.raises(harness.SetupError) as e:
+        harness.resolve(HERE, dict(_olmo(), **{key: stem}))
+    assert key in str(e.value)
+
+
+PHASES = {"forward": 0.052, "backward": 0.17, "backward/recompute": 0.05,
+          "optimizer": 0.045, "sync": 0.11, "sync/Pack": 0.018,
+          "sync/Unpack": 0.004, "sync/C2CRed": 0.031}
+
+
+@pytest.mark.parametrize("name,phases,value", [
+    ("forward_ms", PHASES, 52.0), ("backward_ms", PHASES, 170.0),
+    ("optimizer_ms", PHASES, 45.0), ("sync_pack_ms", PHASES, 22.0),
+    ("sync_pack_ms", {"sync/Pack": 0.018}, 18.0),
+    ("forward_ms", None, None), ("backward_ms", None, None),
+    ("optimizer_ms", None, None), ("sync_pack_ms", None, None),
+    ("forward_ms", {"backward": 0.1}, None),
+    ("sync_pack_ms", {"forward": 0.05, "sync/C2CRed": 0.03}, None)])
+def test_scope_readers(name, phases, value):
+    read = harness.load_reader(HERE / "metrics" / f"{name}.py")
+    got = read({"phases": phases})
+    assert got == (None if value is None else pytest.approx(value))
 
 
 def _copy_bench(dst: pathlib.Path) -> dict:

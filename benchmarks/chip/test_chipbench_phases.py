@@ -1,7 +1,9 @@
-"""The step's phases from a trace (``phases.py``): the reduction by phase
-on a synthetic trace, and, on 4 virtual CPU devices in a child process,
-every op of the compiled (pod, data) = (2, 2) hier step carrying the
-phase, and every sync collective the schedule-IR step, that issued it."""
+"""The step's phases from a trace (``tracereduce.by_phase`` over
+``harness.Program.labels``, read by traced runs and ``phases.py``): the
+reduction by phase on a synthetic trace, and, on 4 virtual CPU devices
+in a child process, every op of the compiled (pod, data) = (2, 2) hier
+step carrying the phase, and every sync collective the schedule-IR step,
+that issued it."""
 
 import json
 import os
@@ -44,7 +46,7 @@ LABELS = {"fusion.1": "forward", "all-reduce.3": "sync/C2CRed",
 
 
 def test_by_phase_known_intervals():
-    out = phases.by_phase(synthetic(), LABELS, steps=2)
+    out = tr.by_phase(synthetic(), LABELS, "traced_window", steps=2)
     ns = 1e-9 / 2 / 2          # over 2 devices and 2 steps
     p = out["phase_s_per_step"]
     assert p["forward"] == pytest.approx((10 + 50) * ns)
@@ -81,12 +83,20 @@ from repro.launch import hlo_analysis as ha
 
 root = ct.make_root(pathlib.Path(sys.argv[1]), "qwen", 4, dtype="float32")
 cell = harness.load_cell(root, "tiny.tiny")
-prog = phases.Scoped(cell, jax.devices()[:4])
+prog = harness.Program(cell, jax.devices()[:4])
 text = prog.compiled.as_text()
 pmap = scopes.phase_map(text, prog.lowered.as_text(dialect="hlo",
                                                    debug_info=True))
-labels, wire = phases.labels_of(prog)
+labels = prog.labels()
+wire = phases.sync_wire_bytes(prog, labels)
+# the benchmark's copy of the scope reading against the program's own
 table = ha.instructions(text)
+program_labels = {}
+for n, ph in pmap.items():
+    if ph == scopes.BACKWARD and scopes.is_recompute(table[n].op_name):
+        ph += "/recompute"
+    if ph != scopes.OTHER:
+        program_labels[n] = ph
 comps, entry = ha._split_computations(text)
 fused = ha._fused_comps(comps)
 top = [ha._DEF_RE.match(ln).group(1) for c in comps.values()
@@ -109,7 +119,8 @@ out = {"schedule": [type(s).__name__ for s in
                        if pmap[n] == "other" and not scalar(n)
                        and table[n].opcode not in ("parameter", "constant",
                                                    "tuple")),
-       "labels": sorted(set(labels.values())), "wire": wire}
+       "labels": sorted(set(labels.values())), "wire": wire,
+       "same_as_program": labels == program_labels, "n_labels": len(labels)}
 print(json.dumps(out))
 """
 
@@ -154,5 +165,8 @@ def test_labels_and_wire_bytes(four):
             "step_metrics", "sync/Pack", "sync/IntraReduceScatter",
             "sync/C2CRed", "sync/IntraAllGather"} <= set(four["labels"])
     assert "other" not in four["labels"]
+    # the benchmark's own reading of the scopes gives every instruction
+    # the scope the program's phase_map gives it
+    assert four["same_as_program"] and four["n_labels"] > 100
     # the packed gradient's RS, pod all-reduce and AG send bytes
     assert four["wire"] > 0

@@ -20,6 +20,9 @@ one host span (the traced window) and gives, averaged over devices:
   seconds in the window per device;
 * ``idle_gaps``: the ten longest gaps between ops on the first device,
   each named by the host span that overlaps it most (or ``none``).
+
+``by_phase`` gives the same window's device time by the named scope of
+each op's HLO instruction.
 """
 
 from __future__ import annotations
@@ -189,3 +192,43 @@ def reduce(trace: Trace, window_span: str, steps: int) -> dict | None:
                        sorted(by_op.items(), key=lambda kv: -kv[1])[:10]],
         "idle_gaps": [[cover(g), (g[1] - g[0]) * ns] for g in idle],
     }
+
+
+def by_phase(trace: Trace, labels: dict, window_span: str,
+             steps: int) -> dict:
+    """Seconds a step, averaged over the devices, of the union of the
+    intervals of each scope's ops (``labels``: {instruction: scope
+    path}; synchronous and asynchronous ops, control flow aside; ``a/b``
+    counting for ``a`` too), of the ops of no scope, and of the
+    collectives under ``sync``, in the traced window.  The trace holds a
+    device op and the window span (``reduce`` is not None)."""
+    lo, hi = [(s, e) for n, s, e in trace.spans if n == window_span][0]
+    devices = sorted(d for d in trace.ops if trace.ops[d])
+
+    def seconds(intervals):
+        return measure(clip(union(intervals), lo, hi))
+
+    phases: dict = {}
+    unscoped = sync = 0.0
+    for d in devices:
+        ops = [o for o in trace.ops[d] + trace.async_ops.get(d, [])
+               if o.opcode not in CONTROL]
+        spans: dict = {}
+        for o in ops:
+            parts = labels.get(o.name, "").split("/")
+            for k in range(1, len(parts) + 1):
+                spans.setdefault("/".join(parts[:k]), []).append(
+                    (o.start, o.end))
+        for key, iv in spans.items():
+            if key:
+                phases[key] = phases.get(key, 0.0) + seconds(iv)
+            else:
+                unscoped += seconds(iv)
+        sync += seconds((o.start, o.end) for o in ops
+                        if labels.get(o.name, "").startswith("sync/")
+                        and is_collective(o))
+    per = 1e-9 / len(devices) / steps
+    return {"phase_s_per_step": {k: v * per
+                                 for k, v in sorted(phases.items())},
+            "unscoped_s_per_step": unscoped * per,
+            "sync_collective_s_per_step": sync * per}
